@@ -5,13 +5,40 @@ executable cache and is re-exported from :mod:`repro.core.plan` for
 backwards compatibility; it lives here so leaf modules that ``plan``
 itself imports (e.g. :mod:`repro.core.flatbuf`'s layout cache) can use the
 same LRU without an import cycle.
+
+:func:`enable_persistent_cache` points JAX's on-disk compilation cache at
+one fixed directory, so a second process of the same program loads its
+executables instead of compiling them again.
 """
 from __future__ import annotations
 
+import os
 from collections import OrderedDict
 from typing import Any, Callable
 
-__all__ = ["CompileCache"]
+__all__ = ["CompileCache", "enable_persistent_cache"]
+
+# <checkout>/.jax_cache: this file is <checkout>/src/repro/core/cache.py
+_CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.dirname(os.path.abspath(__file__)))))
+
+
+def enable_persistent_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and
+    nothing is set here.  Otherwise the cache lives at the fixed path
+    ``<checkout>/.jax_cache``: the directory is part of what a later run
+    must find again, so it never comes from a temp name, a pid or the time.
+    Entry points call this before their first compile, never at import."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    import jax
+
+    path = os.path.join(_CHECKOUT, ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 class CompileCache:

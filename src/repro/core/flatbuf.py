@@ -31,6 +31,7 @@ reshape/concat/slice -- XLA fuses them into the surrounding computation.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any
 
 import jax
@@ -69,10 +70,17 @@ class GroupLayout:
     slots: tuple           # tuple[LeafSlot, ...] in leaf order
     size: int              # used columns (sum of slot sizes)
     padded: int            # allocated columns (size rounded up to tile grid)
-    # (padded,) int32: element -> slot position within this group; padding
-    # elements map to len(slots).  Consumed by the per-leaf int8 scale
-    # expansion in gossip.mix_shifts.
-    seg_ids: np.ndarray
+
+    @functools.cached_property
+    def seg_ids(self) -> np.ndarray:
+        """(padded,) int32: element -> slot position within this group;
+        padding elements map to len(slots).  Consumed only by the per-leaf
+        int8 scale expansion, so it is built on first use: at a published
+        width it is one int32 per payload element (GBs of host memory)."""
+        seg = np.full((self.padded,), len(self.slots), np.int32)
+        for pos, s in enumerate(self.slots):
+            seg[s.offset:s.offset + s.size] = pos
+        return seg
 
 
 @dataclasses.dataclass(frozen=True, eq=False)
@@ -133,11 +141,8 @@ def layout_of(tree: PyTree, pad_multiple: int = PAD_MULTIPLE) -> FlatLayout:
                 size = int(np.prod(leaves[i].shape[1:], dtype=np.int64))
                 slots.append(LeafSlot(i, off, size, tuple(leaves[i].shape)))
                 off += size
-            padded = _pad_up(off, pad_multiple)
-            seg = np.full((padded,), len(slots), np.int32)
-            for pos, s in enumerate(slots):
-                seg[s.offset:s.offset + s.size] = pos
-            groups.append(GroupLayout(dt, tuple(slots), off, padded, seg))
+            groups.append(GroupLayout(dt, tuple(slots), off,
+                                      _pad_up(off, pad_multiple)))
 
         return FlatLayout(treedef, int(n), tuple(groups), len(leaves))
 

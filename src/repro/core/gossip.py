@@ -146,14 +146,12 @@ def mix_dense(tree: PyTree, W, *, mesh=None, axis_name: str = "node",
     if (not isinstance(W, jax.core.Tracer)
             and np.asarray(W).shape[0] == n
             and _shard_native(mesh, axis_name, n)):
-        from jax.experimental.shard_map import shard_map
-
         Wnp = np.asarray(W, np.float64)
         spec_tree = _resolve_specs(tree, specs, axis_name)
-        return shard_map(
+        return jax.shard_map(
             lambda t: _local_dense(t, Wnp, axis_name), mesh=mesh,
             in_specs=(spec_tree,), out_specs=spec_tree,
-            check_rep=False)(tree)
+            check_vma=False)(tree)
     layout, bufs = flatbuf.pack(tree)
     Wl = jnp.asarray(W).astype(jnp.float32)
     out = [jnp.einsum("ij,jb->ib", Wl, b.astype(jnp.float32)).astype(b.dtype)
@@ -309,8 +307,6 @@ def _mix_sharded(tree: PyTree, *, mesh, specs, axis_name: str, rounds: list,
     ``rounds`` is ``[(ppermute send pairs, weight), ...]``; the per-shard
     body is :func:`_local_round` -- the payload is never resharded and
     inner-dim (fsdp/model) shardings pass through untouched."""
-    from jax.experimental.shard_map import shard_map
-
     spec_tree = _resolve_specs(tree, specs, axis_name)
     inner_axes = tuple(a for a in mesh.axis_names if a != axis_name)
     fixed_arr = None if fixed is None else jnp.asarray(fixed)
@@ -320,8 +316,8 @@ def _mix_sharded(tree: PyTree, *, mesh, specs, axis_name: str, rounds: list,
                             compression=compression, fixed_arr=fixed_arr,
                             axis_name=axis_name, inner_axes=inner_axes)
 
-    return shard_map(local_fn, mesh=mesh, in_specs=(spec_tree,),
-                     out_specs=spec_tree, check_rep=False)(tree)
+    return jax.shard_map(local_fn, mesh=mesh, in_specs=(spec_tree,),
+                         out_specs=spec_tree, check_vma=False)(tree)
 
 
 def _shift_pairs(n: int, shift: int) -> list:
@@ -472,7 +468,6 @@ def _runtime_mix(tree: PyTree, *, rounds: list, base_ws: list, self_w,
     meta_mat, n_user, has_gate = _assemble_meta(meta, node_gate)
 
     if _shard_native(mesh, axis_name, n):
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
 
         spec_tree = _resolve_specs(tree, specs, axis_name)
@@ -493,8 +488,9 @@ def _runtime_mix(tree: PyTree, *, rounds: list, base_ws: list, self_w,
                 edge_weight, keep)
             return flatbuf.unpack(layout, outs)
 
-        return shard_map(local_fn, mesh=mesh, in_specs=(spec_tree, rt_specs),
-                         out_specs=spec_tree, check_rep=False)(tree, rt)
+        return jax.shard_map(local_fn, mesh=mesh,
+                             in_specs=(spec_tree, rt_specs),
+                             out_specs=spec_tree, check_vma=False)(tree, rt)
 
     layout, bufs = flatbuf.pack(tree)
     # receive index: node i receives from the node that SENDS to i
@@ -570,8 +566,6 @@ def pack_payload(tree: PyTree, *, mesh=None, axis_name: str = "node",
     if not _shard_native(mesh, axis_name, n):
         _, bufs = flatbuf.pack(tree)
         return tuple(bufs)
-    from jax.experimental.shard_map import shard_map
-
     spec_tree = _resolve_specs(tree, specs, axis_name)
     ltpl = _local_template(tree, spec_tree, mesh, axis_name)
     n_groups = len(flatbuf.layout_of(ltpl, pad_multiple=1).groups)
@@ -581,9 +575,9 @@ def pack_payload(tree: PyTree, *, mesh=None, axis_name: str = "node",
         _, bufs = flatbuf.pack(t, layout)
         return tuple(bufs)
 
-    return shard_map(local_fn, mesh=mesh, in_specs=(spec_tree,),
-                     out_specs=_buffer_specs(mesh, axis_name, n_groups),
-                     check_rep=False)(tree)
+    return jax.shard_map(local_fn, mesh=mesh, in_specs=(spec_tree,),
+                         out_specs=_buffer_specs(mesh, axis_name, n_groups),
+                         check_vma=False)(tree)
 
 
 def delayed_mix(template: PyTree, bufs, realization, *,
@@ -608,8 +602,6 @@ def delayed_mix(template: PyTree, bufs, realization, *,
         layout = flatbuf.layout_of(template)
         return mix_realization(flatbuf.unpack(layout, bufs), realization,
                                compression=compression)
-    from jax.experimental.shard_map import shard_map
-
     spec_tree = _resolve_specs(template, specs, axis_name)
     ltpl = _local_template(template, spec_tree, mesh, axis_name)
     local_layout = flatbuf.layout_of(ltpl, pad_multiple=1)
@@ -650,10 +642,10 @@ def delayed_mix(template: PyTree, bufs, realization, *,
     else:
         raise TypeError(f"not a realization IR node: {realization!r}")
 
-    return shard_map(
+    return jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(_buffer_specs(mesh, axis_name, len(local_layout.groups)),),
-        out_specs=spec_tree, check_rep=False)(bufs)
+        out_specs=spec_tree, check_vma=False)(bufs)
 
 
 def _is_runtime_round(self_w, ws, meta, edge_weight, node_gate) -> bool:

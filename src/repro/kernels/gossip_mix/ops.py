@@ -19,6 +19,15 @@ def gossip_mix(x, recvs, *, w_self: float, ws: tuple,
     """out = w_self * x + sum_d ws[d] * recvs[d]; any shape/dtype."""
     if interpret is None:
         interpret = not _on_tpu()
+    recvs = list(recvs)
+    if (x.ndim == 2 and x.shape[1] % K.TILE_COLS == 0
+            and (x.shape[0] < K.TILE_ROWS or x.shape[0] % K.TILE_ROWS == 0)):
+        # a packed (rows, B) gossip buffer is tile-aligned as it stands.
+        # Reshaping it to (rows * B / 1024, 1024) is no bitcast on TPU: the
+        # two shapes tile differently in HBM, so each operand and the
+        # result would be copied (three payload-sized temporaries).
+        return K.gossip_mix_kernel(x, recvs, w_self, tuple(ws),
+                                   interpret=interpret)
     shape, dtype = x.shape, x.dtype
     n = x.size
     cols = min(K.TILE_COLS, max(n, 1))

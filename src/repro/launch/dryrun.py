@@ -1,7 +1,13 @@
 import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# The two lines above MUST run before any jax import: jax locks the device
-# count on first initialization. 512 host devices model 2 pods x 256 chips.
+# These lines MUST run before any jax import: jax locks the backend and the
+# device count on first initialization.  The dry-run only compiles, on 512
+# host devices that model 2 pods x 256 chips, so it is pinned to the CPU and
+# never takes an accelerator; the device-count flag is appended to any
+# XLA_FLAGS already set.
+os.environ["JAX_PLATFORMS"] = "cpu"
+os.environ["XLA_FLAGS"] = " ".join(filter(None, [
+    os.environ.get("XLA_FLAGS"),
+    "--xla_force_host_platform_device_count=512"]))
 
 import argparse  # noqa: E402
 import dataclasses  # noqa: E402

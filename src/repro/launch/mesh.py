@@ -16,9 +16,10 @@ jax device state.
 from __future__ import annotations
 
 import jax
+import numpy as np
 from jax.sharding import Mesh
 
-__all__ = ["make_production_mesh", "to_logical_mesh", "HW"]
+__all__ = ["make_production_mesh", "to_logical_mesh", "node_mesh", "HW"]
 
 # TPU v5e hardware constants used by the roofline analysis (per chip).
 HW = {
@@ -57,3 +58,21 @@ def to_logical_mesh(mesh: Mesh, nodes: int, fsdp: int,
         raise ValueError(
             f"nodes*fsdp*model ({nodes}*{fsdp}*{model}) != {total} devices")
     return Mesh(devs.reshape(nodes, fsdp, model), ("node", "fsdp", "model"))
+
+
+def node_mesh(nodes: int) -> Mesh | None:
+    """One decentralized node per visible device: a 1-axis ("node",) mesh.
+
+    ``None`` when a single device is visible -- every node then lives on
+    it, stacked along the leading axis -- or for a single node, which needs
+    no mesh.  Otherwise the node count must equal the device count:
+    anything else would either pile nodes onto one device or leave devices
+    idle."""
+    devs = jax.devices()
+    if len(devs) == 1 or nodes == 1:
+        return None
+    if nodes != len(devs):
+        raise ValueError(
+            f"--nodes {nodes} != {len(devs)} visible devices: the trainer "
+            f"lays one node per device")
+    return Mesh(np.array(devs), ("node",))

@@ -3,9 +3,9 @@ by a Poisson arrival trace.
 
 ``main`` builds a :class:`repro.serve.ServeEngine` and feeds it requests
 as their (virtual) arrival times pass, printing per-request latency
-percentiles, throughput, and page/compile-cache statistics.  CPU demo
-uses REDUCED configs; the production shardings are exercised by the
-decode shapes of the dry-run.
+percentiles, throughput, and page/compile-cache statistics.  ``--full``
+serves the published widths; the default ``--reduced`` keeps the block
+structure at tiny dims (the CPU demo).
 
 The legacy :func:`generate` (one fixed batch, dense ring cache) is kept
 as the serving baseline ``bench_serve`` compares against.  Its prefill
@@ -26,6 +26,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import configs
+from repro.core.cache import enable_persistent_cache
 from repro.core.plan import CompileCache
 from repro.models import model as M
 from repro.models.attention import KVCache
@@ -190,6 +191,8 @@ def latency_summary(finished):
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3-0.6b")
+    ap.add_argument("--reduced", action="store_true", default=True)
+    ap.add_argument("--full", dest="reduced", action="store_false")
     ap.add_argument("--n-requests", type=int, default=16)
     ap.add_argument("--rate", type=float, default=4.0,
                     help="Poisson arrival rate (requests/s)")
@@ -202,8 +205,11 @@ def main() -> None:
     ap.add_argument("--temperature", type=float, default=0.0)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_persistent_cache()
 
-    cfg = configs.reduced_config(configs.get_config(args.arch))
+    cfg = configs.get_config(args.arch)
+    if args.reduced:
+        cfg = configs.reduced_config(cfg)
     params = M.init(cfg, jax.random.key(args.seed))
     engine = ServeEngine(cfg, params, n_pages=args.pages,
                          page_size=args.page_size, max_seq=args.max_seq,

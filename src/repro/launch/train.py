@@ -1,8 +1,10 @@
 """End-to-end decentralized training driver.
 
 Runs DmSGD (or any variant) over any topology on any assigned architecture.
-On CPU it trains REDUCED configs (same block structure); on a real cluster
-the same code path shards over the logical mesh via the dry-run's shardings.
+With one visible device every node is stacked on it along the leading
+axis; with several, one node sits on each device (a ("node",) mesh) and
+every gossip round runs shard-natively.  ``--full`` trains the published
+widths; the default ``--reduced`` keeps the block structure at tiny dims.
 
 Example (CPU):
   PYTHONPATH=src python -m repro.launch.train --arch qwen3-0.6b --reduced \
@@ -15,14 +17,18 @@ import time
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import NamedSharding
+from jax.sharding import PartitionSpec as P
 
 from repro import checkpoint, configs
 from repro.core import flatbuf
 from repro.core import optim as optim_mod
 from repro.core import schedule
 from repro.core import topology as topo_mod
+from repro.core.cache import enable_persistent_cache
 from repro.core.plan import GossipPlan
 from repro.data import SyntheticLM
+from repro.launch import mesh as mesh_mod
 from repro.launch import sharding as sharding_mod
 from repro.launch import steps as steps_mod
 
@@ -30,7 +36,7 @@ from repro.launch import steps as steps_mod
 def build_trainer(cfg, topology, optimizer_name: str, beta: float,
                   micro_batch=None, momentum_dtype=None, warmup_steps=0,
                   mesh=None, payload_specs=None, overlap=False,
-                  loss_aware=False, deadline=False):
+                  loss_aware=False, deadline=False, donate=False):
     """Returns (opt, step_for) where ``step_for(step)`` is the compiled
     train-step callable for that step's gossip realization (the plan
     itself rides along as ``step_for.plan`` -- checkpoint flushes and
@@ -53,6 +59,9 @@ def build_trainer(cfg, topology, optimizer_name: str, beta: float,
     (hidden under that step's backward), the packed payload rides the
     optimizer state as a double buffer, and params + state are DONATED to
     the executable so the buffer rotates in place instead of being copied.
+    ``donate=True`` donates params + state to the synchronous step too: the
+    new iterates then reuse the old ones' memory, which a loop that never
+    reads a step's inputs again (``run``) can afford at published widths.
     """
     opt = optim_mod.make_optimizer(optimizer_name, topology, beta=beta,
                                    momentum_dtype=momentum_dtype,
@@ -70,7 +79,8 @@ def build_trainer(cfg, topology, optimizer_name: str, beta: float,
     step_fn = steps_mod.make_train_step(cfg, opt, micro_batch=micro_batch)
     plan = GossipPlan.for_optimizer(opt, fn=step_fn, mesh=mesh,
                                     specs=payload_specs,
-                                    donate_argnums=(0, 1) if overlap else ())
+                                    donate_argnums=(0, 1) if overlap or donate
+                                    else ())
 
     def step_for(step, **kw):
         return plan.step_fn(step, **kw)
@@ -101,7 +111,26 @@ def consensus_distance(params) -> float:
     return float(jnp.sqrt(_consensus_sq(params)))
 
 
+def _born_on_nodes(fn, mesh, *args):
+    """``jax.jit(fn)(*args)`` with every output leaf that has a leading node
+    axis sharded over the mesh's ``node`` axis (the rest replicated), so
+    node-stacked params and optimizer state are created in place, one
+    node per device, and never assembled on a single device first."""
+    if mesh is None:
+        return jax.jit(fn)(*args)
+    n = mesh.shape["node"]
+    shapes = jax.eval_shape(fn, *args)
+    shard = jax.tree.map(
+        lambda s: NamedSharding(mesh, P("node") if s.ndim and s.shape[0] == n
+                                else P()), shapes)
+    return jax.jit(fn, out_shardings=shard)(*args)
+
+
 def run(args) -> dict:
+    """Train per ``args`` (the CLI namespace).  Returns the logged
+    ``history`` (step, loss, consensus, lr, wall seconds since the first
+    step began), the final node-stacked ``params`` and ``state``, the
+    ``config``, and the ``plan`` the steps ran on."""
     cfg = configs.get_config(args.arch)
     if args.reduced:
         cfg = configs.reduced_config(cfg)
@@ -120,23 +149,34 @@ def run(args) -> dict:
     if straggler_prob and not deadline:
         raise ValueError("--straggler-prob simulates missed deadlines; "
                          "pair it with --deadline-skip")
+    mesh = mesh_mod.node_mesh(n)
     opt, step_for = build_trainer(cfg, top, args.optimizer, args.beta,
                                   args.micro_batch, momentum_dtype=mom_dtype,
-                                  overlap=overlap, loss_aware=loss_aware,
-                                  deadline=deadline)
+                                  mesh=mesh, overlap=overlap,
+                                  loss_aware=loss_aware, deadline=deadline,
+                                  donate=True)
     plan = step_for.plan
+    on_nodes = ((lambda x: jnp.asarray(x)) if mesh is None else
+                (lambda x: jax.device_put(x, NamedSharding(mesh, P("node")))))
 
     from repro.models import model as M
     params = M.init(cfg, jax.random.key(args.seed))
-    stacked = jax.tree.map(lambda p: jnp.broadcast_to(p, (n,) + p.shape),
-                           params)
-    if args.optimizer != "parallel_msgd" and args.desync:
-        # start nodes desynchronized to exercise consensus
+    desync = args.optimizer != "parallel_msgd" and args.desync
+
+    def stack(params):
         stacked = jax.tree.map(
-            lambda p: p + 0.01 * jax.random.normal(
-                jax.random.key(1), p.shape, jnp.float32).astype(p.dtype),
-            stacked)
-    state = opt.init(stacked)
+            lambda p: jnp.broadcast_to(p, (n,) + p.shape), params)
+        if desync:
+            # start nodes desynchronized to exercise consensus
+            stacked = jax.tree.map(
+                lambda p: p + 0.01 * jax.random.normal(
+                    jax.random.key(1), p.shape, jnp.float32).astype(p.dtype),
+                stacked)
+        return stacked
+
+    stacked = _born_on_nodes(stack, mesh, params)
+    del params
+    state = _born_on_nodes(opt.init, mesh, stacked)
 
     data = SyntheticLM(cfg.vocab_size, n, hetero=args.hetero, seed=args.seed)
     lr_fn = schedule.warmup_step_decay(
@@ -147,18 +187,18 @@ def run(args) -> dict:
     for step in range(args.steps):
         batch_np = data.sample(step, args.batch, args.seq,
                                cfg.n_codebooks if cfg.family == "audio" else 0)
-        batch = {"tokens": jnp.asarray(batch_np)}
+        batch = {"tokens": on_nodes(batch_np)}
         if cfg.family == "vlm":
-            batch["image_embeds"] = jax.random.normal(
+            batch["image_embeds"] = on_nodes(jax.random.normal(
                 jax.random.key(step), (n, args.batch, cfg.n_image_tokens,
-                                       cfg.d_model), jnp.float32)
+                                       cfg.d_model), jnp.float32))
         if deadline:
             # simulated stragglers: each node independently misses the
             # round's deadline with prob p; the gossip drops it per node
             # (both directions) and renormalizes the surviving weights
             alive = jax.random.uniform(
                 jax.random.key(2**20 + step), (n,)) >= straggler_prob
-            batch["alive"] = alive
+            batch["alive"] = on_nodes(alive)
         lr = lr_fn(step)
         stacked, state, loss = step_for(step)(stacked, state, batch, lr)
         if step % args.log_every == 0 or step == args.steps - 1:
@@ -168,7 +208,7 @@ def run(args) -> dict:
             ev_params, _ = plan.flush_step_fn(step + 1)(stacked, state)
             cd = consensus_distance(ev_params)
             history.append(dict(step=step, loss=float(loss), consensus=cd,
-                                lr=float(lr)))
+                                lr=float(lr), seconds=time.time() - t0))
             print(f"step {step:5d}  loss {float(loss):.4f}  "
                   f"consensus {cd:.3e}  lr {float(lr):.2e}  "
                   f"({time.time() - t0:.1f}s)")
@@ -188,7 +228,7 @@ def run(args) -> dict:
     if overlap:
         stacked, state = plan.flush_step_fn(args.steps)(stacked, state)
     return {"history": history, "params": stacked, "state": state,
-            "config": cfg}
+            "config": cfg, "plan": plan}
 
 
 def main() -> None:
@@ -236,6 +276,7 @@ def main() -> None:
     ap.add_argument("--ckpt-dir", default=None)
     ap.add_argument("--ckpt-every", type=int, default=50)
     args = ap.parse_args()
+    enable_persistent_cache()
     run(args)
 
 

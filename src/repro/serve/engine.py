@@ -192,7 +192,10 @@ class ServeEngine:
                 self._maybe_finish(r, now)
             # resumed requests re-filled their pages; logits are dropped
 
-    def _run_decode(self, reqs: list[Request], now: float) -> None:
+    def decode_inputs(self, reqs: list[Request]):
+        """``(exe, args)`` of one batched decode step over ``reqs``: the
+        bucketed executable and its arguments (params, last tokens, pool,
+        page table, positions).  ``exe(*args)`` returns (logits, pool)."""
         Bb = _bucket(len(reqs))
         tokens = np.zeros(self._token_shape(Bb, 1), np.int32)
         positions = np.zeros((Bb,), np.int32)
@@ -201,9 +204,12 @@ class ServeEngine:
             tokens[i, 0] = r.generated[-1]
             positions[i] = r.cache_len()
             page_table[i, :len(r.pages)] = r.pages
-        exe = self._decode_exe(Bb)
-        logits, self.pool = exe(self.params, tokens, self.pool, page_table,
-                                positions)
+        return self._decode_exe(Bb), (self.params, tokens, self.pool,
+                                      page_table, positions)
+
+    def _run_decode(self, reqs: list[Request], now: float) -> None:
+        exe, args = self.decode_inputs(reqs)
+        logits, self.pool = exe(*args)
         logits = np.asarray(logits[:, 0], np.float32)
         for i, r in enumerate(reqs):
             r.generated.append(self._sample(logits[i], r))

@@ -5,7 +5,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from tests._hypothesis_compat import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.kernels.flash_attention import ops as fa_ops, ref as fa_ref
 from repro.kernels.gossip_mix import ops as gm_ops, ref as gm_ref

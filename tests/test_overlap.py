@@ -120,8 +120,15 @@ def test_pipelined_bit_identical_to_sequential_delayed(name):
 def test_pipelined_int8_and_every_and_warmup():
     """The overlap pipeline composes with the rest of the transform
     algebra: int8 wire compression, gossip(every=k) Identity off-steps,
-    and the Corollary-3 all-reduce warm-up phase -- each bit-identical to
-    the sequential delayed reference built from the sync executors."""
+    and the Corollary-3 all-reduce warm-up phase -- each matching the
+    sequential delayed reference built from the sync executors.
+
+    To f32 rounding, not bit for bit: with every=2 or int8 the two programs
+    fuse the combine with different neighbours, and on a CPU with FMA
+    instructions LLVM contracts a multiply-add in one fusion and not the
+    other (1 ulp on one element under jax 0.9.0).  Held to SSE4.2
+    (``XLA_FLAGS=--xla_cpu_max_isa=SSE4_2``, no FMA) they agree bit for bit.
+    The plain every=1 pipeline keeps its bit-exact test above."""
     n, T, lr = 4, 8, 0.05
     top = topology.one_peer_exponential(n)
     params = _params(n, seed=3)
@@ -151,8 +158,12 @@ def test_pipelined_int8_and_every_and_warmup():
             for t in range(T):
                 p, s, pay = _sequential_delayed_step(
                     opt_s, sync_plan, t, lr)(p, s, grads[t], pay)
-                _eq(p, hist[t][0], f"int8={bool(kw)} every={every} "
-                    f"warmup={warmup} step {t}")
+                for x, y in zip(jax.tree.leaves(p),
+                                jax.tree.leaves(hist[t][0])):
+                    np.testing.assert_allclose(
+                        np.asarray(x), np.asarray(y), rtol=1e-6, atol=1e-7,
+                        err_msg=f"int8={bool(kw)} every={every} "
+                                f"warmup={warmup} step {t}")
 
 
 def test_delayed_exact_average_over_period():

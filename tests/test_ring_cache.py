@@ -10,7 +10,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from tests._hypothesis_compat import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.models import attention as A
 

@@ -230,12 +230,21 @@ _HLO_2AX_SCRIPT = textwrap.dedent("""
     assert 0 < c.get("collective-permute", 0) <= 2 * (nodes - 1), c
 
     # exact averaging (uniform rows) collapses to ONE psum per group:
-    # all-reduce only, no permutes, no gathers
+    # all-reduce only, no permutes, no gathers.  XLA's all-reduce combiner
+    # may merge the two groups' psums into one tuple all-reduce, so count
+    # the all-reduce OPERANDS: one per dtype group (f32 + bf16).
     fullW = topology.full_averaging(nodes).realization(0)
-    cost = counts(lambda t: gossip.mix_realization(
-        t, fullW, mesh=mesh, specs=specs))
+    f = jax.jit(lambda t: gossip.mix_realization(
+        t, fullW, mesh=mesh, specs=specs),
+        in_shardings=(shard,), out_shardings=shard)
+    txt = f.lower(tree).compile().as_text()
+    cost = analyze_hlo(txt)
     c = cost.collective_counts
-    assert c.get("all-reduce", 0) == 2, c          # f32 + bf16 group
+    operands = sum(line.split(" all-reduce(", 1)[1].split(")", 1)[0]
+                   .count("%") for line in txt.splitlines()
+                   if " all-reduce(" in line)
+    assert operands == 2, (operands, c)
+    assert 1 <= c.get("all-reduce", 0) <= 2, c
     assert c.get("all-gather", 0) == 0, c
     assert c.get("collective-permute", 0) == 0, c
     print("HLO-2AX-OK")

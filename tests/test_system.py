@@ -11,7 +11,10 @@ from repro.models import model as M
 
 
 def _args(**kw):
-    base = dict(arch="qwen3-0.6b", reduced=True, nodes=4,
+    # one node per device where several are visible (the trainer refuses
+    # any other count); four nodes stacked on a single device otherwise
+    nodes = jax.device_count() if jax.device_count() > 1 else 4
+    base = dict(arch="qwen3-0.6b", reduced=True, nodes=nodes,
                 topology="one_peer_exp", optimizer="dmsgd", beta=0.9,
                 steps=25, batch=2, seq=32, lr=0.05, warmup=5, hetero=0.3,
                 micro_batch=None, seed=0, desync=False, log_every=10,
