@@ -116,16 +116,41 @@ def _use_pallas(local: bool) -> bool:
     return local or jax.device_count() == 1
 
 
+# Device scopes of a round's three phases (HLO ``op_name`` metadata only,
+# free at run time; the device trace names every op by them):
+# ``gossip/pack`` packs into and unpacks from the flat wire buffers (and
+# quantizes them), ``gossip/permute`` is the wire (ppermute, psum, or the
+# global path's roll and gather), ``gossip/combine`` the weighted sum.
+def _scope(phase: str):
+    return jax.named_scope(f"gossip/{phase}")
+
+
+def _pack(tree: PyTree, layout: flatbuf.FlatLayout | None = None):
+    with _scope("pack"):
+        return flatbuf.pack(tree, layout)
+
+
+def _unpack(layout: flatbuf.FlatLayout, bufs) -> PyTree:
+    with _scope("pack"):
+        return flatbuf.unpack(layout, bufs)
+
+
+def _ppermute(x, axis_name: str, pairs):
+    with _scope("permute"):
+        return jax.lax.ppermute(x, axis_name, perm=pairs)
+
+
 def _combine(x, recvs, w_self: float, ws: tuple, local: bool = False):
     """out = w_self*x + sum_d ws[d]*recvs[d] over packed buffers."""
-    if _use_pallas(local):
-        from repro.kernels.gossip_mix import ops as gm_ops
-        interpret = True if _PALLAS_MODE == "interpret" else None
-        return gm_ops.gossip_mix(x, recvs, w_self=float(w_self),
-                                 ws=tuple(float(w) for w in ws),
-                                 interpret=interpret)
-    from repro.kernels.gossip_mix import ref as gm_ref
-    return gm_ref.gossip_mix_ref(x, recvs, float(w_self), ws)
+    with _scope("combine"):
+        if _use_pallas(local):
+            from repro.kernels.gossip_mix import ops as gm_ops
+            interpret = True if _PALLAS_MODE == "interpret" else None
+            return gm_ops.gossip_mix(x, recvs, w_self=float(w_self),
+                                     ws=tuple(float(w) for w in ws),
+                                     interpret=interpret)
+        from repro.kernels.gossip_mix import ref as gm_ref
+        return gm_ref.gossip_mix_ref(x, recvs, float(w_self), ws)
 
 
 def mix_dense(tree: PyTree, W, *, mesh=None, axis_name: str = "node",
@@ -152,11 +177,13 @@ def mix_dense(tree: PyTree, W, *, mesh=None, axis_name: str = "node",
             lambda t: _local_dense(t, Wnp, axis_name), mesh=mesh,
             in_specs=(spec_tree,), out_specs=spec_tree,
             check_vma=False)(tree)
-    layout, bufs = flatbuf.pack(tree)
+    layout, bufs = _pack(tree)
     Wl = jnp.asarray(W).astype(jnp.float32)
-    out = [jnp.einsum("ij,jb->ib", Wl, b.astype(jnp.float32)).astype(b.dtype)
-           for b in bufs]
-    return flatbuf.unpack(layout, out)
+    with _scope("combine"):
+        out = [jnp.einsum("ij,jb->ib", Wl,
+                          b.astype(jnp.float32)).astype(b.dtype)
+               for b in bufs]
+    return _unpack(layout, out)
 
 
 def _scale_columns(leaves, layout: flatbuf.FlatLayout, inner_axes: tuple = ()):
@@ -229,37 +256,42 @@ def _local_round(t: PyTree, *, rounds: list, self_w: float,
     their value bit-exactly."""
     ws = tuple(w for _, w in rounds)
     layout = flatbuf.layout_of(t, pad_multiple=1)
-    layout, bufs = flatbuf.pack(t, layout)
+    layout, bufs = _pack(t, layout)
     keep = (None if fixed_arr is None
             else fixed_arr[jax.lax.axis_index(axis_name)])
     out = []
     if compression == "int8":
-        scales = _scale_columns(jax.tree.leaves(t), layout, inner_axes)
+        with _scope("pack"):
+            scales = _scale_columns(jax.tree.leaves(t), layout, inner_axes)
         for g, buf, sc in zip(layout.groups, bufs, scales):
             seg = jnp.asarray(g.seg_ids)
             x32 = buf.astype(jnp.float32)
-            q = jnp.round(x32 / sc[:, seg]).astype(jnp.int8)
-            acc = (self_w * x32) if self_w else None
+            with _scope("pack"):
+                q = jnp.round(x32 / sc[:, seg]).astype(jnp.int8)
+            with _scope("combine"):
+                acc = (self_w * x32) if self_w else None
             for pairs, w in rounds:
-                rq = jax.lax.ppermute(q, axis_name, perm=pairs)
-                rs = jax.lax.ppermute(sc, axis_name, perm=pairs)
-                r = w * (rq.astype(jnp.float32) * rs[:, seg])
-                acc = r if acc is None else acc + r
-            if keep is not None:
-                # fixed points keep their FULL-PRECISION buffer (never
-                # the quantized image, and never the w_self*x +
-                # w_peer*x blend, which is only exact for w_self=0.5)
-                acc = jnp.where(keep, x32, acc)
-            out.append(acc.astype(buf.dtype))
+                rq = _ppermute(q, axis_name, pairs)
+                rs = _ppermute(sc, axis_name, pairs)
+                with _scope("combine"):
+                    r = w * (rq.astype(jnp.float32) * rs[:, seg])
+                    acc = r if acc is None else acc + r
+            with _scope("combine"):
+                if keep is not None:
+                    # fixed points keep their FULL-PRECISION buffer (never
+                    # the quantized image, and never the w_self*x +
+                    # w_peer*x blend, which is only exact for w_self=0.5)
+                    acc = jnp.where(keep, x32, acc)
+                out.append(acc.astype(buf.dtype))
     else:
         for buf in bufs:
-            recvs = [jax.lax.ppermute(buf, axis_name, perm=pairs)
-                     for pairs, _ in rounds]
+            recvs = [_ppermute(buf, axis_name, pairs) for pairs, _ in rounds]
             o = _combine(buf, recvs, self_w, ws, local=True)
             if keep is not None:
-                o = jnp.where(keep, buf, o)
+                with _scope("combine"):
+                    o = jnp.where(keep, buf, o)
             out.append(o)
-    return flatbuf.unpack(layout, out)
+    return _unpack(layout, out)
 
 
 def _local_dense(t: PyTree, W: np.ndarray, axis_name: str) -> PyTree:
@@ -274,15 +306,16 @@ def _local_dense(t: PyTree, W: np.ndarray, axis_name: str) -> PyTree:
     no GSPMD reshard of the payload on multi-axis meshes."""
     n = W.shape[0]
     layout = flatbuf.layout_of(t, pad_multiple=1)
-    layout, bufs = flatbuf.pack(t, layout)
+    layout, bufs = _pack(t, layout)
     i = jax.lax.axis_index(axis_name)
     out = []
     if np.allclose(W, W[0:1, :]):
         row = jnp.asarray(W[0], jnp.float32)
         for buf in bufs:
-            o = jax.lax.psum(row[i] * buf.astype(jnp.float32), axis_name)
+            with _scope("permute"):
+                o = jax.lax.psum(row[i] * buf.astype(jnp.float32), axis_name)
             out.append(o.astype(buf.dtype))
-        return flatbuf.unpack(layout, out)
+        return _unpack(layout, out)
     diag = jnp.asarray(np.ascontiguousarray(np.diagonal(W)), jnp.float32)
     shifts = []
     for s in range(1, n):
@@ -290,13 +323,15 @@ def _local_dense(t: PyTree, W: np.ndarray, axis_name: str) -> PyTree:
         if np.any(col):
             shifts.append((s, jnp.asarray(col, jnp.float32)))
     for buf in bufs:
-        acc = diag[i] * buf.astype(jnp.float32)
+        with _scope("combine"):
+            acc = diag[i] * buf.astype(jnp.float32)
         for s, col in shifts:
-            recv = jax.lax.ppermute(buf, axis_name,
-                                    perm=_shift_pairs(n, s))
-            acc = acc + col[i] * recv.astype(jnp.float32)
-        out.append(acc.astype(buf.dtype))
-    return flatbuf.unpack(layout, out)
+            recv = _ppermute(buf, axis_name, _shift_pairs(n, s))
+            with _scope("combine"):
+                acc = acc + col[i] * recv.astype(jnp.float32)
+        with _scope("combine"):
+            out.append(acc.astype(buf.dtype))
+    return _unpack(layout, out)
 
 
 def _mix_sharded(tree: PyTree, *, mesh, specs, axis_name: str, rounds: list,
@@ -406,41 +441,45 @@ def _runtime_combine(bufs: list, layout: flatbuf.FlatLayout, permute,
     for d in range(D):
         for j, buf in enumerate(bufs):
             if j == gi and meta_mat is not None:
-                aug = jnp.concatenate(
-                    [buf, meta_mat.astype(buf.dtype)], axis=1)
-                r = permute(aug, d)
+                with _scope("pack"):
+                    aug = jnp.concatenate(
+                        [buf, meta_mat.astype(buf.dtype)], axis=1)
+                with _scope("permute"):
+                    r = permute(aug, d)
                 recvs[j][d] = r[:, :buf.shape[1]]
                 recv_meta[d] = r[:, buf.shape[1]:].astype(jnp.float32)
             else:
-                recvs[j][d] = permute(buf, d)
-    own_user = meta_mat[:, :n_user] if n_user else None
-    own_alive = meta_mat[:, -1] > 0.5 if has_gate else None
-    eff = []
-    for d in range(D):
-        w = base_ws[d]
-        if edge_weight is not None:
-            w = edge_weight(own_user, recv_meta[d][:, :n_user]
-                            if n_user else None, w)
-        w = jnp.asarray(w, jnp.float32)
-        if has_gate:
-            both = jnp.logical_and(own_alive, recv_meta[d][:, -1] > 0.5)
-            w = jnp.where(both, w, jnp.zeros_like(w))
-        eff.append(w)
-    if self_w is None or has_gate or edge_weight is not None:
-        # dropped-edge mass returns to self: rows stay stochastic
-        self_col = 1.0 - sum(_wcol(w) for w in eff)
-    else:
-        self_col = _wcol(self_w)
-    outs = []
-    for j, buf in enumerate(bufs):
-        x32 = buf.astype(jnp.float32)
-        acc = self_col * x32
+                with _scope("permute"):
+                    recvs[j][d] = permute(buf, d)
+    with _scope("combine"):
+        own_user = meta_mat[:, :n_user] if n_user else None
+        own_alive = meta_mat[:, -1] > 0.5 if has_gate else None
+        eff = []
         for d in range(D):
-            acc = acc + _wcol(eff[d]) * recvs[j][d].astype(jnp.float32)
-        if keep is not None:
-            acc = jnp.where(keep, x32, acc)
-        outs.append(acc.astype(buf.dtype))
-    return outs
+            w = base_ws[d]
+            if edge_weight is not None:
+                w = edge_weight(own_user, recv_meta[d][:, :n_user]
+                                if n_user else None, w)
+            w = jnp.asarray(w, jnp.float32)
+            if has_gate:
+                both = jnp.logical_and(own_alive, recv_meta[d][:, -1] > 0.5)
+                w = jnp.where(both, w, jnp.zeros_like(w))
+            eff.append(w)
+        if self_w is None or has_gate or edge_weight is not None:
+            # dropped-edge mass returns to self: rows stay stochastic
+            self_col = 1.0 - sum(_wcol(w) for w in eff)
+        else:
+            self_col = _wcol(self_w)
+        outs = []
+        for j, buf in enumerate(bufs):
+            x32 = buf.astype(jnp.float32)
+            acc = self_col * x32
+            for d in range(D):
+                acc = acc + _wcol(eff[d]) * recvs[j][d].astype(jnp.float32)
+            if keep is not None:
+                acc = jnp.where(keep, x32, acc)
+            outs.append(acc.astype(buf.dtype))
+        return outs
 
 
 def _runtime_operands(n: int, self_w, base_ws: list, meta_mat):
@@ -477,7 +516,7 @@ def _runtime_mix(tree: PyTree, *, rounds: list, base_ws: list, self_w,
 
         def local_fn(t, rt):
             layout = flatbuf.layout_of(t, pad_multiple=1)
-            layout, bufs = flatbuf.pack(t, layout)
+            layout, bufs = _pack(t, layout)
             keep = (None if fixed_arr is None
                     else fixed_arr[jax.lax.axis_index(axis_name)])
             outs = _runtime_combine(
@@ -486,13 +525,13 @@ def _runtime_mix(tree: PyTree, *, rounds: list, base_ws: list, self_w,
                                                 perm=rounds[d]),
                 list(rt["ws"]), rt["self"], rt["meta"], n_user, has_gate,
                 edge_weight, keep)
-            return flatbuf.unpack(layout, outs)
+            return _unpack(layout, outs)
 
         return jax.shard_map(local_fn, mesh=mesh,
                              in_specs=(spec_tree, rt_specs),
                              out_specs=spec_tree, check_vma=False)(tree, rt)
 
-    layout, bufs = flatbuf.pack(tree)
+    layout, bufs = _pack(tree)
     # receive index: node i receives from the node that SENDS to i
     idxs = []
     for pairs in rounds:
@@ -505,7 +544,7 @@ def _runtime_mix(tree: PyTree, *, rounds: list, base_ws: list, self_w,
     outs = _runtime_combine(
         bufs, layout, lambda arr, d: jnp.take(arr, idxs[d], axis=0),
         base_ws, self_w, meta_mat, n_user, has_gate, edge_weight, keep)
-    return flatbuf.unpack(layout, outs)
+    return _unpack(layout, outs)
 
 
 # ---------------------------------------------------------------------------
@@ -564,7 +603,7 @@ def pack_payload(tree: PyTree, *, mesh=None, axis_name: str = "node",
     delayed mixing is bit-identical to it."""
     n = _node_count(tree)
     if not _shard_native(mesh, axis_name, n):
-        _, bufs = flatbuf.pack(tree)
+        _, bufs = _pack(tree)
         return tuple(bufs)
     spec_tree = _resolve_specs(tree, specs, axis_name)
     ltpl = _local_template(tree, spec_tree, mesh, axis_name)
@@ -572,7 +611,7 @@ def pack_payload(tree: PyTree, *, mesh=None, axis_name: str = "node",
 
     def local_fn(t):
         layout = flatbuf.layout_of(t, pad_multiple=1)
-        _, bufs = flatbuf.pack(t, layout)
+        _, bufs = _pack(t, layout)
         return tuple(bufs)
 
     return jax.shard_map(local_fn, mesh=mesh, in_specs=(spec_tree,),
@@ -600,7 +639,7 @@ def delayed_mix(template: PyTree, bufs, realization, *,
     n = int(leaves[0].shape[0])
     if not _shard_native(mesh, axis_name, n):
         layout = flatbuf.layout_of(template)
-        return mix_realization(flatbuf.unpack(layout, bufs), realization,
+        return mix_realization(_unpack(layout, bufs), realization,
                                compression=compression)
     spec_tree = _resolve_specs(template, specs, axis_name)
     ltpl = _local_template(template, spec_tree, mesh, axis_name)
@@ -609,7 +648,7 @@ def delayed_mix(template: PyTree, bufs, realization, *,
 
     if isinstance(realization, Identity):
         def local_fn(bs):
-            return flatbuf.unpack(local_layout, list(bs))
+            return _unpack(local_layout, list(bs))
     elif isinstance(realization, Dense):
         if compression is not None:
             raise ValueError(
@@ -618,7 +657,7 @@ def delayed_mix(template: PyTree, bufs, realization, *,
         Wnp = np.asarray(realization.W, np.float64)
 
         def local_fn(bs):
-            return _local_dense(flatbuf.unpack(local_layout, list(bs)),
+            return _local_dense(_unpack(local_layout, list(bs)),
                                 Wnp, axis_name)
     elif isinstance(realization, (Shifts, Matching)):
         if isinstance(realization, Shifts):
@@ -634,7 +673,7 @@ def delayed_mix(template: PyTree, bufs, realization, *,
             fixed_arr = jnp.asarray(fixed) if fixed.any() else None
 
         def local_fn(bs):
-            t = flatbuf.unpack(local_layout, list(bs))
+            t = _unpack(local_layout, list(bs))
             return _local_round(t, rounds=rounds, self_w=self_w,
                                 compression=compression,
                                 fixed_arr=fixed_arr, axis_name=axis_name,
@@ -708,30 +747,37 @@ def mix_shifts(tree: PyTree, self_weight: float,
                             axis_name=axis_name, rounds=rounds,
                             self_w=self_weight, compression=compression)
 
-    layout, bufs = flatbuf.pack(tree)
+    layout, bufs = _pack(tree)
     ws = tuple(w for _, w in shifts)
 
     if compression == "int8":
-        scales = _leaf_scales(tree, layout)
+        with _scope("pack"):
+            scales = _leaf_scales(tree, layout)
         out = []
         for g, buf, sc in zip(layout.groups, bufs, scales):
             seg = jnp.asarray(g.seg_ids)
             x32 = buf.astype(jnp.float32)
-            q = jnp.round(x32 / sc[:, seg]).astype(jnp.int8)
-            acc = (self_weight * x32) if self_weight else None
+            with _scope("pack"):
+                q = jnp.round(x32 / sc[:, seg]).astype(jnp.int8)
+            with _scope("combine"):
+                acc = (self_weight * x32) if self_weight else None
             for s, w in shifts:
-                rq = jnp.roll(q, s, axis=0)        # int8 over the wire
-                rs = jnp.roll(sc, s, axis=0)       # tiny per-leaf scales
-                r = w * (rq.astype(jnp.float32) * rs[:, seg])
-                acc = r if acc is None else acc + r
-            out.append(acc.astype(buf.dtype))
-        return flatbuf.unpack(layout, out)
+                with _scope("permute"):
+                    rq = jnp.roll(q, s, axis=0)    # int8 over the wire
+                    rs = jnp.roll(sc, s, axis=0)   # tiny per-leaf scales
+                with _scope("combine"):
+                    r = w * (rq.astype(jnp.float32) * rs[:, seg])
+                    acc = r if acc is None else acc + r
+            with _scope("combine"):
+                out.append(acc.astype(buf.dtype))
+        return _unpack(layout, out)
 
     out = []
     for buf in bufs:
-        recvs = [jnp.roll(buf, s, axis=0) for s, _ in shifts]
+        with _scope("permute"):
+            recvs = [jnp.roll(buf, s, axis=0) for s, _ in shifts]
         out.append(_combine(buf, recvs, self_weight, ws))
-    return flatbuf.unpack(layout, out)
+    return _unpack(layout, out)
 
 
 def mix_matching(tree: PyTree, partner: tuple, w_self: float = 0.5,
@@ -792,35 +838,42 @@ def mix_matching(tree: PyTree, partner: tuple, w_self: float = 0.5,
                             self_w=w_self, compression=compression,
                             fixed=fixed_mask)
 
-    layout, bufs = flatbuf.pack(tree)
+    layout, bufs = _pack(tree)
     idx = jnp.asarray(partner)
 
     if compression == "int8":
-        scales = _leaf_scales(tree, layout)
+        with _scope("pack"):
+            scales = _leaf_scales(tree, layout)
         out = []
         for g, buf, sc in zip(layout.groups, bufs, scales):
             seg = jnp.asarray(g.seg_ids)
             x32 = buf.astype(jnp.float32)
-            q = jnp.round(x32 / sc[:, seg]).astype(jnp.int8)
-            rq = jnp.take(q, idx, axis=0)
-            rs = jnp.take(sc, idx, axis=0)
-            acc = w_self * x32 + w_peer * (rq.astype(jnp.float32)
-                                           * rs[:, seg])
-            if fixed_mask is not None:
-                # fixed points keep their full-precision buffer bit-exactly
-                # (for ANY w_self, not just 0.5)
-                acc = jnp.where(jnp.asarray(fixed_mask)[:, None], x32, acc)
-            out.append(acc.astype(buf.dtype))
-        return flatbuf.unpack(layout, out)
+            with _scope("pack"):
+                q = jnp.round(x32 / sc[:, seg]).astype(jnp.int8)
+            with _scope("permute"):
+                rq = jnp.take(q, idx, axis=0)
+                rs = jnp.take(sc, idx, axis=0)
+            with _scope("combine"):
+                acc = w_self * x32 + w_peer * (rq.astype(jnp.float32)
+                                               * rs[:, seg])
+                if fixed_mask is not None:
+                    # fixed points keep their full-precision buffer
+                    # bit-exactly (for ANY w_self, not just 0.5)
+                    acc = jnp.where(jnp.asarray(fixed_mask)[:, None], x32,
+                                    acc)
+                out.append(acc.astype(buf.dtype))
+        return _unpack(layout, out)
 
     out = []
     for buf in bufs:
-        recv = jnp.take(buf, idx, axis=0)
+        with _scope("permute"):
+            recv = jnp.take(buf, idx, axis=0)
         o = _combine(buf, [recv], w_self, (w_peer,))
         if fixed_mask is not None:
-            o = jnp.where(jnp.asarray(fixed_mask)[:, None], buf, o)
+            with _scope("combine"):
+                o = jnp.where(jnp.asarray(fixed_mask)[:, None], buf, o)
         out.append(o)
-    return flatbuf.unpack(layout, out)
+    return _unpack(layout, out)
 
 
 def mix_shifts_per_leaf(tree: PyTree, self_weight: float,

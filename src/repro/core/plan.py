@@ -72,6 +72,14 @@ PyTree = Any
 __all__ = ["CompileCache", "GossipPlan", "OverlapIO"]
 
 
+def _named_after(call: Callable, fn: Callable) -> Callable:
+    """``call`` renamed after the step function it wraps, so its jitted
+    executable (HLO module ``jit_<name>``, the module the device trace
+    shows) is ``train_step`` and not ``<lambda>``."""
+    call.__name__ = call.__qualname__ = getattr(fn, "__name__", "step")
+    return call
+
+
 @dataclasses.dataclass(frozen=True)
 class OverlapIO:
     """Gossip I/O bundle for one overlapped (delayed-mix) step.
@@ -387,7 +395,8 @@ class GossipPlan:
                 return fn(lambda t, **kw: gossip.mix_realization(
                     t, r, compression=comp, mesh=mesh, specs=specs, **kw),
                     *a)
-            return jax.jit(call, **self._jit_kwargs(extra_leading=1))
+            return jax.jit(_named_after(call, fn),
+                           **self._jit_kwargs(extra_leading=1))
 
         return self._cache.get(key, build)
 
@@ -412,7 +421,8 @@ class GossipPlan:
         realized ``W^{(k)}`` as its leading traced argument."""
         fn = self._require_fn()
         return self._cache.get(("dense",), lambda: jax.jit(
-            lambda W, *a: fn((lambda t: gossip.mix_dense(t, W)), *a),
+            _named_after(lambda W, *a: fn(
+                (lambda t: gossip.mix_dense(t, W)), *a), fn),
             **self._jit_kwargs(extra_leading=1)))
 
     def _realized_W(self, step: int) -> jax.Array:
@@ -442,7 +452,8 @@ class GossipPlan:
                 key = self.realization_key(step)
                 io = self.overlap_io(step)
             return self._cache.get(key, lambda: jax.jit(
-                lambda *a: fn(io, *a), **self._jit_kwargs()))
+                _named_after(lambda *a: fn(io, *a), fn),
+                **self._jit_kwargs()))
         key = self.realization_key(step)
         if key == ("dense",):
             jitted = self._dense_executable()
@@ -461,7 +472,7 @@ class GossipPlan:
                 return lambda *a: jitted(wvals, *a)
         mix = self.mix(step)
         return self._cache.get(key, lambda: jax.jit(
-            lambda *a: fn(mix, *a), **self._jit_kwargs()))
+            _named_after(lambda *a: fn(mix, *a), fn), **self._jit_kwargs()))
 
     def flush_step_fn(self, step: int) -> Callable:
         """Compiled drain of the overlap pipeline at python step ``step``:
@@ -480,7 +491,7 @@ class GossipPlan:
         io = self.overlap_io(step)
         flush = self.flush_fn
         return self._cache.get(key, lambda: jax.jit(
-            lambda *a: flush(io, *a)))
+            _named_after(lambda *a: flush(io, *a), flush)))
 
     def lowered(self, step: int, *args):
         """``jax.jit(...).lower(*args)`` for ``step``'s executable -- for

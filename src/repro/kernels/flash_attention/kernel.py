@@ -119,4 +119,5 @@ def flash_attention_kernel(q, k, v, *, causal: bool = True,
             pltpu.VMEM((block_q, D), jnp.float32),   # output accumulator
         ],
         interpret=interpret,
+        name="flash_attention",
     )(q, k, v)

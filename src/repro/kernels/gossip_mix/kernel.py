@@ -60,4 +60,5 @@ def gossip_mix_kernel(x, recvs, w_self: float, ws: tuple,
         # still needed afterwards
         input_output_aliases={0: 0},
         interpret=interpret,
+        name="gossip_mix",
     )(x, *recvs)

@@ -132,4 +132,5 @@ def paged_attention_kernel(q, k_pages, v_pages, page_table, lengths, *,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Kv, G, D), q.dtype),
         interpret=interpret,
+        name="paged_attention",
     )(page_table, lengths, q, kp, vp)

@@ -102,5 +102,6 @@ def ssd_scan_kernel(x, dt, dA, B, C, *, chunk: int = 128,
         ],
         scratch_shapes=[pltpu.VMEM((N, P), jnp.float32)],
         interpret=interpret,
+        name="ssd_scan",
     )(x, dt, dA, B, C)
     return y, hT

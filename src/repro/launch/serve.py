@@ -161,10 +161,10 @@ def serve_trace(engine: ServeEngine, trace, *, realtime: bool = False):
         if realtime:
             now = time.perf_counter() - t0
         while i < len(pending) and pending[i][0] <= now:
-            a, prompt, max_new = pending[i]
-            engine.submit(prompt, max_new, arrival=a)
+            _, prompt, max_new = pending[i]
+            engine.submit(prompt, max_new)
             i += 1
-        worked = engine.step(now=now)
+        worked = engine.step()
         if not realtime:
             now = time.perf_counter() - t0
         if not worked and not engine.sched.waiting and not engine.sched.running:
@@ -176,8 +176,10 @@ def serve_trace(engine: ServeEngine, trace, *, realtime: bool = False):
 
 
 def latency_summary(finished):
-    first = np.array([r.t_first_token - r.arrival for r in finished])
-    total = np.array([r.t_finish - r.arrival for r in finished])
+    """Seconds from each request's submission to its first and its last
+    token on the host (the engine's own ``perf_counter`` stamps)."""
+    first = np.array([r.t_first_token - r.t_submit for r in finished])
+    total = np.array([r.t_finish - r.t_submit for r in finished])
 
     def pct(a, q):
         return float(np.percentile(a, q)) if len(a) else float("nan")

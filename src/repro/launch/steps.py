@@ -113,16 +113,22 @@ def train_loss_fn(params, cfg: M.ModelConfig, tokens, image_embeds=None,
         # training uses the GShard capacity dispatch (active-param FLOPs);
         # the dropless exact mixture is the serving/eval path.
         cfg = dataclasses.replace(cfg, moe_dropless=False)
-    logits, aux = M.forward(params, cfg, tokens, image_embeds=image_embeds)
-    labels = jnp.roll(tokens, -1, axis=1)
-    lo = logits.astype(jnp.float32)            # (..., V), V possibly sharded
-    mx = jax.lax.stop_gradient(jnp.max(lo, axis=-1, keepdims=True))
-    lse = jnp.squeeze(mx, -1) + jnp.log(jnp.sum(jnp.exp(lo - mx), axis=-1))
-    col = jax.lax.broadcasted_iota(jnp.int32, lo.shape, lo.ndim - 1)
-    label_logit = jnp.sum(jnp.where(col == labels[..., None], lo, 0.0),
-                          axis=-1)
-    ce = (lse - label_logit).mean()
-    return ce + aux_weight * aux
+    # device scope: the backward pass of these ops is named
+    # ``transpose(jvp(forward))``, the remat recompute inside it
+    # ``.../rematted_computation/...``
+    with jax.named_scope("forward"):
+        logits, aux = M.forward(params, cfg, tokens,
+                                image_embeds=image_embeds)
+        labels = jnp.roll(tokens, -1, axis=1)
+        lo = logits.astype(jnp.float32)        # (..., V), V possibly sharded
+        mx = jax.lax.stop_gradient(jnp.max(lo, axis=-1, keepdims=True))
+        lse = jnp.squeeze(mx, -1) + jnp.log(jnp.sum(jnp.exp(lo - mx),
+                                                    axis=-1))
+        col = jax.lax.broadcasted_iota(jnp.int32, lo.shape, lo.ndim - 1)
+        label_logit = jnp.sum(jnp.where(col == labels[..., None], lo, 0.0),
+                              axis=-1)
+        ce = (lse - label_logit).mean()
+        return ce + aux_weight * aux
 
 
 def make_train_step(cfg: M.ModelConfig,
@@ -187,8 +193,9 @@ def make_train_step(cfg: M.ModelConfig,
             losses, grads = jax.vmap(per_node_grads)(params, tokens,
                                                      image_embeds)
         if opt.overlap:
-            new_params, new_state = opt.update_pipelined(
-                params, opt_state, grads, lr, mix)
+            with jax.named_scope("optimizer"):
+                new_params, new_state = opt.update_pipelined(
+                    params, opt_state, grads, lr, mix)
         else:
             aux = None
             if getattr(opt, "has_runtime_gossip", False):
@@ -199,8 +206,9 @@ def make_train_step(cfg: M.ModelConfig,
                 for key in ("alive", "comm"):
                     if key in batch:
                         aux[key] = batch[key]
-            new_params, new_state = opt.update_with_mix(
-                params, opt_state, grads, lr, mix, aux=aux)
+            with jax.named_scope("optimizer"):
+                new_params, new_state = opt.update_with_mix(
+                    params, opt_state, grads, lr, mix, aux=aux)
         return new_params, new_state, losses.mean()
 
     return train_step

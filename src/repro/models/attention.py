@@ -215,8 +215,9 @@ def attn_decode_paged(params, x, k_pages, v_pages, page_table, positions, *,
     slots = positions % page_size
     kn = k_new[:, 0].transpose(1, 0, 2)          # (Kv, B, hd)
     vn = v_new[:, 0].transpose(1, 0, 2)
-    k_pages = k_pages.at[:, pages, slots].set(kn.astype(k_pages.dtype))
-    v_pages = v_pages.at[:, pages, slots].set(vn.astype(v_pages.dtype))
+    with jax.named_scope("kv_write"):
+        k_pages = k_pages.at[:, pages, slots].set(kn.astype(k_pages.dtype))
+        v_pages = v_pages.at[:, pages, slots].set(vn.astype(v_pages.dtype))
     lengths = positions + 1
 
     static_window = window is None or isinstance(window, int)

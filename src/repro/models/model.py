@@ -232,21 +232,23 @@ def _effective_window(cfg: ModelConfig, is_local):
 def _dense_block(cfg: ModelConfig, p, x, positions, is_local, aux,
                  collect_kv=False):
     h = rms_norm(p["ln1"], x, cfg.norm_eps)
-    out = attn.attn_apply(
-        p["attn"], h, n_heads=cfg.n_heads, n_kv=cfg.n_kv_heads,
-        head_dim=cfg.head_dim, positions=positions,
-        rope_theta=cfg.rope_theta, qk_norm=cfg.qk_norm,
-        window=_effective_window(cfg, is_local),
-        attn_cap=cfg.attn_softcap, impl=cfg.attention_impl,
-        gqa_layout=cfg.gqa_layout, return_kv=collect_kv)
+    with jax.named_scope("attention"):
+        out = attn.attn_apply(
+            p["attn"], h, n_heads=cfg.n_heads, n_kv=cfg.n_kv_heads,
+            head_dim=cfg.head_dim, positions=positions,
+            rope_theta=cfg.rope_theta, qk_norm=cfg.qk_norm,
+            window=_effective_window(cfg, is_local),
+            attn_cap=cfg.attn_softcap, impl=cfg.attention_impl,
+            gqa_layout=cfg.gqa_layout, return_kv=collect_kv)
     h, kv = (out[0], out[1:]) if collect_kv else (out, None)
     x = x + h
     h = rms_norm(p["ln2"], x, cfg.norm_eps)
     if "moe" in p:
-        h, aux_l = moe_mod.moe_apply(p["moe"], h, n_experts=cfg.n_experts,
-                                     top_k=cfg.top_k,
-                                     capacity_factor=cfg.capacity_factor,
-                                     dropless=cfg.moe_dropless)
+        with jax.named_scope("moe"):
+            h, aux_l = moe_mod.moe_apply(
+                p["moe"], h, n_experts=cfg.n_experts, top_k=cfg.top_k,
+                capacity_factor=cfg.capacity_factor,
+                dropless=cfg.moe_dropless)
         aux = aux + aux_l
     else:
         h = mlp_apply(p["mlp"], h, cfg.mlp_kind)
@@ -302,8 +304,9 @@ def forward(params: PyTree, cfg: ModelConfig, tokens, *, image_embeds=None,
             return (x, aux), None
 
         body = _maybe_remat(body, cfg)
-        (x, aux), _ = jax.lax.scan(body, (x, aux0),
-                                   (params["layers"], local_flags))
+        with jax.named_scope("layers"):
+            (x, aux), _ = jax.lax.scan(body, (x, aux0),
+                                       (params["layers"], local_flags))
     elif fam == "ssm":
         def body(carry, p):
             return _mamba_block(cfg, p, carry), None
@@ -386,8 +389,9 @@ def forward_prefill(params: PyTree, cfg: ModelConfig, tokens, *,
                                       collect_kv=True)
         return (x, aux), (k, v)
 
-    (x, _), (k_all, v_all) = jax.lax.scan(body, (x, aux0),
-                                          (params["layers"], local_flags))
+    with jax.named_scope("layers"):
+        (x, _), (k_all, v_all) = jax.lax.scan(
+            body, (x, aux0), (params["layers"], local_flags))
     x = rms_norm(params["final_norm"], x, cfg.norm_eps)
     return _lm_head(params, cfg, x), (k_all, v_all)
 
@@ -434,16 +438,17 @@ def _hybrid_forward(params, cfg, x, positions, aux):
 
 
 def _lm_head(params, cfg, x):
-    if cfg.family == "audio":
-        return jnp.einsum("bsd,kdv->bskv", x,
-                          params["lm_head"].astype(x.dtype))
-    if cfg.tie_embeddings:
-        logits = jnp.einsum("bsd,vd->bsv", x,
-                            params["embed"].astype(x.dtype))
-    else:
-        logits = jnp.einsum("bsd,dv->bsv", x,
-                            params["lm_head"].astype(x.dtype))
-    return softcap(logits, cfg.final_softcap)
+    with jax.named_scope("lm_head"):
+        if cfg.family == "audio":
+            return jnp.einsum("bsd,kdv->bskv", x,
+                              params["lm_head"].astype(x.dtype))
+        if cfg.tie_embeddings:
+            logits = jnp.einsum("bsd,vd->bsv", x,
+                                params["embed"].astype(x.dtype))
+        else:
+            logits = jnp.einsum("bsd,dv->bsv", x,
+                                params["lm_head"].astype(x.dtype))
+        return softcap(logits, cfg.final_softcap)
 
 
 def _local_flags(cfg: ModelConfig, n: int | None = None):
@@ -508,19 +513,22 @@ def decode_step(params: PyTree, cfg: ModelConfig, token, cache: PyTree, idx,
     def dense_decode(p, x, kvc, is_local):
         h = rms_norm(p["ln1"], x, cfg.norm_eps)
         window = _effective_window(cfg, is_local)
-        h, kvc = attn.attn_decode(
-            p["attn"], h, kvc, idx, n_heads=cfg.n_heads,
-            n_kv=cfg.n_kv_heads, head_dim=cfg.head_dim,
-            rope_theta=cfg.rope_theta, qk_norm=cfg.qk_norm,
-            window=window, attn_cap=cfg.attn_softcap)
+        with jax.named_scope("attention"):
+            h, kvc = attn.attn_decode(
+                p["attn"], h, kvc, idx, n_heads=cfg.n_heads,
+                n_kv=cfg.n_kv_heads, head_dim=cfg.head_dim,
+                rope_theta=cfg.rope_theta, qk_norm=cfg.qk_norm,
+                window=window, attn_cap=cfg.attn_softcap)
         x = x + h
         h = rms_norm(p["ln2"], x, cfg.norm_eps)
         if "moe" in p:
             # decode is always dropless: a capacity drop here would make a
             # token's logits depend on co-batched requests (and diverge
             # from prefill).
-            h, _ = moe_mod.moe_apply(p["moe"], h, n_experts=cfg.n_experts,
-                                     top_k=cfg.top_k, dropless=True)
+            with jax.named_scope("moe"):
+                h, _ = moe_mod.moe_apply(p["moe"], h,
+                                         n_experts=cfg.n_experts,
+                                         top_k=cfg.top_k, dropless=True)
         else:
             h = mlp_apply(p["mlp"], h, cfg.mlp_kind)
         return x + h, kvc
@@ -533,8 +541,10 @@ def decode_step(params: PyTree, cfg: ModelConfig, token, cache: PyTree, idx,
             x, kvc = dense_decode(p, x, attn.KVCache(*kvc), flag)
             return x, (kvc.k, kvc.v)
 
-        x, new_kv = jax.lax.scan(
-            body, x, (params["layers"], (cache["kv"].k, cache["kv"].v), flags))
+        with jax.named_scope("layers"):
+            x, new_kv = jax.lax.scan(
+                body, x, (params["layers"], (cache["kv"].k, cache["kv"].v),
+                          flags))
         new_cache = {"kv": attn.KVCache(*new_kv)}
     elif fam == "ssm":
         def body(x, inp):
@@ -611,24 +621,29 @@ def decode_step_paged(params: PyTree, cfg: ModelConfig, token, pool,
     def body(x, inp):
         p, kp, vp, flag = inp
         h = rms_norm(p["ln1"], x, cfg.norm_eps)
-        h, kp, vp = attn.attn_decode_paged(
-            p["attn"], h, kp, vp, page_table, positions,
-            page_size=page_size, n_heads=cfg.n_heads, n_kv=cfg.n_kv_heads,
-            head_dim=cfg.head_dim, rope_theta=cfg.rope_theta,
-            qk_norm=cfg.qk_norm, window=_effective_window(cfg, flag),
-            attn_cap=cfg.attn_softcap, impl=cfg.attention_impl)
+        with jax.named_scope("attention"):
+            h, kp, vp = attn.attn_decode_paged(
+                p["attn"], h, kp, vp, page_table, positions,
+                page_size=page_size, n_heads=cfg.n_heads,
+                n_kv=cfg.n_kv_heads, head_dim=cfg.head_dim,
+                rope_theta=cfg.rope_theta, qk_norm=cfg.qk_norm,
+                window=_effective_window(cfg, flag),
+                attn_cap=cfg.attn_softcap, impl=cfg.attention_impl)
         x = x + h
         h = rms_norm(p["ln2"], x, cfg.norm_eps)
         if "moe" in p:
             # decode is always dropless (see decode_step)
-            h, _ = moe_mod.moe_apply(p["moe"], h, n_experts=cfg.n_experts,
-                                     top_k=cfg.top_k, dropless=True)
+            with jax.named_scope("moe"):
+                h, _ = moe_mod.moe_apply(p["moe"], h,
+                                         n_experts=cfg.n_experts,
+                                         top_k=cfg.top_k, dropless=True)
         else:
             h = mlp_apply(p["mlp"], h, cfg.mlp_kind)
         return x + h, (kp, vp)
 
-    x, (k_all, v_all) = jax.lax.scan(
-        body, x, (params["layers"], pool["k"], pool["v"], flags))
+    with jax.named_scope("layers"):
+        x, (k_all, v_all) = jax.lax.scan(
+            body, x, (params["layers"], pool["k"], pool["v"], flags))
     x = rms_norm(params["final_norm"], x, cfg.norm_eps)
     logits = _lm_head(params, cfg, x)
     return logits, {"k": k_all, "v": v_all}

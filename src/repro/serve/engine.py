@@ -20,12 +20,35 @@ how it was co-batched, preempted, or resumed.  Audio configs split that
 step key once more per codebook -- K independent streams, not one key
 reused K times.  ``temperature=0`` is greedy argmax (exactly reproducible
 against a dense-cache decode of the same request).
+
+Measurement, all free unless a profiler session is open:
+
+* Host spans (``jax.profiler.TraceAnnotation``, on the profiler's clock
+  with the device ops): ``repro.serve.step`` wraps a step;
+  ``repro.serve.plan`` the scheduler; for each of ``decode`` and
+  ``prefill``, ``repro.serve.<phase>.inputs`` (tokens, page tables,
+  positions), ``.dispatch`` (the executable call) and ``.fetch`` (the
+  logits to the host: where the host waits on the device); and
+  ``repro.serve.sample``.
+* Executables are named ``serve_prefill`` and ``serve_decode``; inside
+  them the model's device scopes (``layers``, the layer loop with its
+  slicing of the stacked weights and pool; ``attention``, ``moe``,
+  ``lm_head``) and ``kv_write`` (the pool scatter) name the ops.
+* Counters (:meth:`ServeEngine.stats`): ``decoded_tokens``,
+  ``prefill_tokens`` (real tokens prefilled) and ``prefill_slots`` (rows
+  times length of the bucketed prefill calls).
+* Each request's ``t_submit``, ``t_admit``, ``t_first_token`` and
+  ``t_finish`` on ``time.perf_counter``; a token is stamped once it is on
+  the host.
 """
 from __future__ import annotations
+
+import time
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.cache import CompileCache
 from repro.models import model as M
@@ -79,10 +102,12 @@ class ServeEngine:
         self._next_rid = 0
         self.n_steps = 0
         self.decoded_tokens = 0
+        self.prefill_tokens = 0
+        self.prefill_slots = 0
 
     # -- request intake ----------------------------------------------------
 
-    def submit(self, prompt, max_new: int, arrival: float = 0.0) -> Request:
+    def submit(self, prompt, max_new: int) -> Request:
         prompt = np.asarray(prompt, np.int32)
         if max_new < 1:
             raise ValueError("max_new must be >= 1")
@@ -91,7 +116,7 @@ class ServeEngine:
                 f"request needs {prompt.shape[0] + max_new} tokens > "
                 f"max_seq={self.max_seq}")
         req = Request(rid=self._next_rid, prompt=prompt, max_new=max_new,
-                      arrival=arrival)
+                      t_submit=time.perf_counter())
         self._next_rid += 1
         self.sched.submit(req)
         return req
@@ -102,23 +127,24 @@ class ServeEngine:
         cfg = self.cfg
 
         def build():
-            def fn(params, tokens, positions, pool, page_idx, slot_idx,
-                   last_idx):
+            def serve_prefill(params, tokens, positions, pool, page_idx,
+                              slot_idx, last_idx):
                 logits, (k, v) = M.forward_prefill(params, cfg, tokens,
                                                    positions=positions)
                 # (L, B, S, Kv, hd) -> (L, Kv, B, S, hd) to match the pool's
                 # advanced-index result layout at dims (pages, slots)
-                k = k.transpose(0, 3, 1, 2, 4)
-                v = v.transpose(0, 3, 1, 2, 4)
-                kp = pool["k"].at[:, :, page_idx, slot_idx].set(
-                    k.astype(pool["k"].dtype))
-                vp = pool["v"].at[:, :, page_idx, slot_idx].set(
-                    v.astype(pool["v"].dtype))
+                with jax.named_scope("kv_write"):
+                    k = k.transpose(0, 3, 1, 2, 4)
+                    v = v.transpose(0, 3, 1, 2, 4)
+                    kp = pool["k"].at[:, :, page_idx, slot_idx].set(
+                        k.astype(pool["k"].dtype))
+                    vp = pool["v"].at[:, :, page_idx, slot_idx].set(
+                        v.astype(pool["v"].dtype))
                 idx = last_idx.reshape((-1,) + (1,) * (logits.ndim - 1))
                 last = jnp.take_along_axis(logits, idx, axis=1)[:, 0]
                 return last, {"k": kp, "v": vp}
 
-            return jax.jit(fn)
+            return jax.jit(serve_prefill)
 
         return self.compile_cache.get(("prefill", Bb, Lb), build)
 
@@ -126,12 +152,12 @@ class ServeEngine:
         cfg, page_size = self.cfg, self.page_size
 
         def build():
-            def fn(params, token, pool, page_table, positions):
+            def serve_decode(params, token, pool, page_table, positions):
                 return M.decode_step_paged(params, cfg, token, pool,
                                            page_table, positions,
                                            page_size=page_size)
 
-            return jax.jit(fn)
+            return jax.jit(serve_decode)
 
         return self.compile_cache.get(("decode", Bb), build)
 
@@ -164,33 +190,39 @@ class ServeEngine:
             return lead + (self.cfg.n_codebooks,)
         return lead
 
-    def _run_prefill(self, reqs: list[Request], now: float) -> None:
-        toks = [r.prefill_tokens() for r in reqs]
-        Bb = _bucket(len(reqs))
-        Lb = _bucket(max(t.shape[0] for t in toks), lo=self.page_size)
-        tokens = np.zeros(self._token_shape(Bb, Lb), np.int32)
-        page_idx = np.full((Bb, Lb), TRASH_PAGE, np.int32)
-        slot_idx = np.broadcast_to(
-            np.arange(Lb, dtype=np.int32) % self.page_size, (Bb, Lb)).copy()
-        last_idx = np.zeros((Bb,), np.int32)
-        for i, (r, t) in enumerate(zip(reqs, toks)):
-            n = t.shape[0]
-            tokens[i, :n] = t
-            pages = np.asarray(r.pages, np.int32)
-            page_idx[i, :n] = pages[np.arange(n) // self.page_size]
-            last_idx[i] = n - 1
-        positions = np.broadcast_to(np.arange(Lb, dtype=np.int32), (Bb, Lb))
-        exe = self._prefill_exe(Bb, Lb)
-        last_logits, self.pool = exe(self.params, tokens, positions,
-                                     self.pool, page_idx, slot_idx, last_idx)
-        last_logits = np.asarray(last_logits, np.float32)
-        for i, r in enumerate(reqs):
-            if not r.generated:          # fresh: sample the first token
-                r.generated.append(self._sample(last_logits[i], r))
-                if r.t_first_token is None:
-                    r.t_first_token = now
-                self._maybe_finish(r, now)
-            # resumed requests re-filled their pages; logits are dropped
+    def _run_prefill(self, reqs: list[Request]) -> None:
+        with TraceAnnotation("repro.serve.prefill.inputs"):
+            toks = [r.prefill_tokens() for r in reqs]
+            Bb = _bucket(len(reqs))
+            Lb = _bucket(max(t.shape[0] for t in toks), lo=self.page_size)
+            tokens = np.zeros(self._token_shape(Bb, Lb), np.int32)
+            page_idx = np.full((Bb, Lb), TRASH_PAGE, np.int32)
+            slot_idx = np.broadcast_to(
+                np.arange(Lb, dtype=np.int32) % self.page_size,
+                (Bb, Lb)).copy()
+            last_idx = np.zeros((Bb,), np.int32)
+            for i, (r, t) in enumerate(zip(reqs, toks)):
+                n = t.shape[0]
+                tokens[i, :n] = t
+                pages = np.asarray(r.pages, np.int32)
+                page_idx[i, :n] = pages[np.arange(n) // self.page_size]
+                last_idx[i] = n - 1
+            positions = np.broadcast_to(np.arange(Lb, dtype=np.int32),
+                                        (Bb, Lb))
+            exe = self._prefill_exe(Bb, Lb)
+        self.prefill_tokens += sum(t.shape[0] for t in toks)
+        self.prefill_slots += Bb * Lb
+        with TraceAnnotation("repro.serve.prefill.dispatch"):
+            last_logits, self.pool = exe(self.params, tokens, positions,
+                                         self.pool, page_idx, slot_idx,
+                                         last_idx)
+        with TraceAnnotation("repro.serve.prefill.fetch"):
+            last_logits = np.asarray(last_logits, np.float32)
+        with TraceAnnotation("repro.serve.sample"):
+            for i, r in enumerate(reqs):
+                if not r.generated:      # fresh: sample the first token
+                    self._append(r, self._sample(last_logits[i], r))
+                # resumed requests re-filled their pages; logits are dropped
 
     def decode_inputs(self, reqs: list[Request]):
         """``(exe, args)`` of one batched decode step over ``reqs``: the
@@ -207,33 +239,46 @@ class ServeEngine:
         return self._decode_exe(Bb), (self.params, tokens, self.pool,
                                       page_table, positions)
 
-    def _run_decode(self, reqs: list[Request], now: float) -> None:
-        exe, args = self.decode_inputs(reqs)
-        logits, self.pool = exe(*args)
-        logits = np.asarray(logits[:, 0], np.float32)
-        for i, r in enumerate(reqs):
-            r.generated.append(self._sample(logits[i], r))
-            self.decoded_tokens += 1
-            if r.t_first_token is None:
-                r.t_first_token = now
-            self._maybe_finish(r, now)
+    def _run_decode(self, reqs: list[Request]) -> None:
+        with TraceAnnotation("repro.serve.decode.inputs"):
+            exe, args = self.decode_inputs(reqs)
+        with TraceAnnotation("repro.serve.decode.dispatch"):
+            logits, self.pool = exe(*args)
+        with TraceAnnotation("repro.serve.decode.fetch"):
+            logits = np.asarray(logits[:, 0], np.float32)
+        with TraceAnnotation("repro.serve.sample"):
+            for i, r in enumerate(reqs):
+                self._append(r, self._sample(logits[i], r))
+                self.decoded_tokens += 1
 
-    def _maybe_finish(self, req: Request, now: float) -> None:
+    def _append(self, req: Request, token) -> None:
+        """A sampled token, on the host: stamp it and finish the request
+        if it is its last."""
+        req.generated.append(token)
+        now = time.perf_counter()
+        if req.t_first_token is None:
+            req.t_first_token = now
         if req.done:
             req.t_finish = now
             self.sched.finish(req)
             self.finished.append(req)
 
-    def step(self, now: float = 0.0) -> bool:
+    def step(self) -> bool:
         """One engine step.  Returns True if any work ran."""
-        plan = self.sched.plan()
-        if plan.decode:
-            self._run_decode(plan.decode, now)
-        if plan.prefill:
-            self._run_prefill(plan.prefill, now)
-        if not plan.empty:
-            self.n_steps += 1
-        return not plan.empty
+        with TraceAnnotation("repro.serve.step"):
+            with TraceAnnotation("repro.serve.plan"):
+                plan = self.sched.plan()
+                now = time.perf_counter()
+                for r in plan.prefill:
+                    if r.t_admit is None:
+                        r.t_admit = now
+            if plan.decode:
+                self._run_decode(plan.decode)
+            if plan.prefill:
+                self._run_prefill(plan.prefill)
+            if not plan.empty:
+                self.n_steps += 1
+            return not plan.empty
 
     def run(self, max_steps: int = 10_000) -> list[Request]:
         """Drive steps until every submitted request finishes."""
@@ -255,6 +300,8 @@ class ServeEngine:
     def stats(self) -> dict:
         s = self.sched.stats()
         s.update(steps=self.n_steps, decoded_tokens=self.decoded_tokens,
+                 prefill_tokens=self.prefill_tokens,
+                 prefill_slots=self.prefill_slots,
                  finished=len(self.finished),
                  peak_kv_bytes=self.peak_kv_bytes(),
                  compile_cache=self.compile_cache.stats())

@@ -40,11 +40,14 @@ class Request:
     rid: int
     prompt: np.ndarray              # (P,) int32 -- audio: (P, K)
     max_new: int
-    arrival: float = 0.0
     state: str = WAITING
     generated: list = dataclasses.field(default_factory=list)
     pages: list[int] = dataclasses.field(default_factory=list)
     preemptions: int = 0
+    # ``time.perf_counter()`` stamps: submitted, first admitted to a
+    # prefill, first and last token on the host
+    t_submit: float | None = None
+    t_admit: float | None = None
     t_first_token: float | None = None
     t_finish: float | None = None
 
