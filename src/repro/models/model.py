@@ -229,8 +229,33 @@ def _effective_window(cfg: ModelConfig, is_local):
     return cfg.sliding_window
 
 
+def _split_experts(layers: PyTree, n_layers: int, dropless: bool):
+    """Take a dropless mixture's expert stacks out of the scanned layers.
+
+    Returns ``(scanned, experts, layer_ids)``.  ``experts`` holds the whole
+    ``[L, E, ...]`` expert stacks, which the layer scan closes over and
+    ``moe.moe_apply`` reads in place at ``(layer, expert)``; ``layer_ids``
+    (``arange(L)``) rides the scan to name the layer.  Were the scan to
+    slice each layer's ``[E, ...]`` slab, the slab would be an operand of
+    the nested expert loop, which XLA copies whole every step.  Layers
+    without a dropless mixture scan as they are: ``experts`` and
+    ``layer_ids`` are None (an empty scan input)."""
+    if not (dropless and "moe" in layers):
+        return layers, None, None
+    moe_p = dict(layers["moe"])
+    experts = {n: moe_p.pop(n) for n in moe_mod.EXPERT_WEIGHTS}
+    return {**layers, "moe": moe_p}, experts, jnp.arange(n_layers)
+
+
+def _moe_params(p, experts):
+    """The mixture's parameters for one step of the layer scan: the
+    layer's router, plus the closed-over expert stacks where they were
+    split out by :func:`_split_experts`."""
+    return p["moe"] if experts is None else {**p["moe"], **experts}
+
+
 def _dense_block(cfg: ModelConfig, p, x, positions, is_local, aux,
-                 collect_kv=False):
+                 collect_kv=False, experts=None, layer=None):
     h = rms_norm(p["ln1"], x, cfg.norm_eps)
     with jax.named_scope("attention"):
         out = attn.attn_apply(
@@ -246,9 +271,9 @@ def _dense_block(cfg: ModelConfig, p, x, positions, is_local, aux,
     if "moe" in p:
         with jax.named_scope("moe"):
             h, aux_l = moe_mod.moe_apply(
-                p["moe"], h, n_experts=cfg.n_experts, top_k=cfg.top_k,
-                capacity_factor=cfg.capacity_factor,
-                dropless=cfg.moe_dropless)
+                _moe_params(p, experts), h, n_experts=cfg.n_experts,
+                top_k=cfg.top_k, capacity_factor=cfg.capacity_factor,
+                dropless=cfg.moe_dropless, layer=layer)
         aux = aux + aux_l
     else:
         h = mlp_apply(p["mlp"], h, cfg.mlp_kind)
@@ -296,17 +321,20 @@ def forward(params: PyTree, cfg: ModelConfig, tokens, *, image_embeds=None,
     fam = cfg.family
     if fam in ("dense", "moe", "audio"):
         local_flags = _local_flags(cfg)
+        layers, experts, layer_ids = _split_experts(
+            params["layers"], cfg.n_layers, cfg.moe_dropless)
 
         def body(carry, inp):
             x, aux = carry
-            p, flag = inp
-            x, aux = _dense_block(cfg, p, x, positions, flag, aux)
+            p, flag, l = inp
+            x, aux = _dense_block(cfg, p, x, positions, flag, aux,
+                                  experts=experts, layer=l)
             return (x, aux), None
 
         body = _maybe_remat(body, cfg)
         with jax.named_scope("layers"):
             (x, aux), _ = jax.lax.scan(body, (x, aux0),
-                                       (params["layers"], local_flags))
+                                       (layers, local_flags, layer_ids))
     elif fam == "ssm":
         def body(carry, p):
             return _mamba_block(cfg, p, carry), None
@@ -381,17 +409,20 @@ def forward_prefill(params: PyTree, cfg: ModelConfig, tokens, *,
                                      (rows, S))
     aux0 = jnp.zeros((), jnp.float32)
     local_flags = _local_flags(cfg)
+    layers, experts, layer_ids = _split_experts(
+        params["layers"], cfg.n_layers, cfg.moe_dropless)
 
     def body(carry, inp):
         x, aux = carry
-        p, flag = inp
+        p, flag, l = inp
         x, aux, (k, v) = _dense_block(cfg, p, x, positions, flag, aux,
-                                      collect_kv=True)
+                                      collect_kv=True, experts=experts,
+                                      layer=l)
         return (x, aux), (k, v)
 
     with jax.named_scope("layers"):
         (x, _), (k_all, v_all) = jax.lax.scan(
-            body, (x, aux0), (params["layers"], local_flags))
+            body, (x, aux0), (layers, local_flags, layer_ids))
     x = rms_norm(params["final_norm"], x, cfg.norm_eps)
     return _lm_head(params, cfg, x), (k_all, v_all)
 
@@ -510,7 +541,7 @@ def decode_step(params: PyTree, cfg: ModelConfig, token, cache: PyTree, idx,
     x = _embed_tokens(params, cfg, token)
     fam = cfg.family
 
-    def dense_decode(p, x, kvc, is_local):
+    def dense_decode(p, x, kvc, is_local, experts=None, layer=None):
         h = rms_norm(p["ln1"], x, cfg.norm_eps)
         window = _effective_window(cfg, is_local)
         with jax.named_scope("attention"):
@@ -526,25 +557,29 @@ def decode_step(params: PyTree, cfg: ModelConfig, token, cache: PyTree, idx,
             # token's logits depend on co-batched requests (and diverge
             # from prefill).
             with jax.named_scope("moe"):
-                h, _ = moe_mod.moe_apply(p["moe"], h,
+                h, _ = moe_mod.moe_apply(_moe_params(p, experts), h,
                                          n_experts=cfg.n_experts,
-                                         top_k=cfg.top_k, dropless=True)
+                                         top_k=cfg.top_k, dropless=True,
+                                         layer=layer)
         else:
             h = mlp_apply(p["mlp"], h, cfg.mlp_kind)
         return x + h, kvc
 
     if fam in ("dense", "moe", "audio"):
         flags = _local_flags(cfg)
+        layers, experts, layer_ids = _split_experts(
+            params["layers"], cfg.n_layers, dropless=True)
 
         def body(x, inp):
-            p, kvc, flag = inp
-            x, kvc = dense_decode(p, x, attn.KVCache(*kvc), flag)
+            p, kvc, flag, l = inp
+            x, kvc = dense_decode(p, x, attn.KVCache(*kvc), flag,
+                                  experts=experts, layer=l)
             return x, (kvc.k, kvc.v)
 
         with jax.named_scope("layers"):
             x, new_kv = jax.lax.scan(
-                body, x, (params["layers"], (cache["kv"].k, cache["kv"].v),
-                          flags))
+                body, x, (layers, (cache["kv"].k, cache["kv"].v), flags,
+                          layer_ids))
         new_cache = {"kv": attn.KVCache(*new_kv)}
     elif fam == "ssm":
         def body(x, inp):
@@ -617,9 +652,11 @@ def decode_step_paged(params: PyTree, cfg: ModelConfig, token, pool,
             f"decode_step_paged supports {PAGED_FAMILIES}, not {cfg.family}")
     x = _embed_tokens(params, cfg, token)
     flags = _local_flags(cfg)
+    layers, experts, layer_ids = _split_experts(
+        params["layers"], cfg.n_layers, dropless=True)
 
     def body(x, inp):
-        p, kp, vp, flag = inp
+        p, kp, vp, flag, l = inp
         h = rms_norm(p["ln1"], x, cfg.norm_eps)
         with jax.named_scope("attention"):
             h, kp, vp = attn.attn_decode_paged(
@@ -634,16 +671,17 @@ def decode_step_paged(params: PyTree, cfg: ModelConfig, token, pool,
         if "moe" in p:
             # decode is always dropless (see decode_step)
             with jax.named_scope("moe"):
-                h, _ = moe_mod.moe_apply(p["moe"], h,
+                h, _ = moe_mod.moe_apply(_moe_params(p, experts), h,
                                          n_experts=cfg.n_experts,
-                                         top_k=cfg.top_k, dropless=True)
+                                         top_k=cfg.top_k, dropless=True,
+                                         layer=l)
         else:
             h = mlp_apply(p["mlp"], h, cfg.mlp_kind)
         return x + h, (kp, vp)
 
     with jax.named_scope("layers"):
         x, (k_all, v_all) = jax.lax.scan(
-            body, x, (params["layers"], pool["k"], pool["v"], flags))
+            body, x, (layers, pool["k"], pool["v"], flags, layer_ids))
     x = rms_norm(params["final_norm"], x, cfg.norm_eps)
     logits = _lm_head(params, cfg, x)
     return logits, {"k": k_all, "v": v_all}

@@ -9,8 +9,12 @@ library at a time; the tests skip where it cannot be described.  The
 persistent compilation cache stays off around these compiles: an entry
 written for a described chip cannot be read back without one.
 """
+import dataclasses
+import re
+
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
@@ -20,8 +24,14 @@ from repro.kernels.flash_attention import ops as fa_ops
 from repro.kernels.gossip_mix import ops as gm_ops
 from repro.kernels.paged_attention import ops as pa_ops
 from repro.models import model as M
+from repro.serve import ServeEngine
 
 QWEN3 = configs.get_config("qwen3-0.6b")
+# granite's published widths, served in bf16; two layers keep the compile
+# short and still give the layer loop a stacked operand to slice
+GRANITE = dataclasses.replace(configs.get_config("granite-moe-3b-a800m"),
+                              n_layers=2, param_dtype=jnp.bfloat16,
+                              remat=False)
 
 
 @pytest.fixture(scope="module")
@@ -100,3 +110,42 @@ def test_flash_attention_compiles_at_qwen3_prefill_widths(one_chip, seq):
     compiled = fa_ops.flash_attention.lower(
         q, kv, kv, causal=True, interpret=False).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def _granite_serving_hlo(one_chip, exe):
+    """The engine's ``serve_decode`` (8 rows, page 16) or ``serve_prefill``
+    (1 x 512) at granite's widths, compiled for the described chip."""
+    cfg = GRANITE
+    eng = ServeEngine(cfg, None, n_pages=64, page_size=16, max_seq=2560,
+                      max_batch=8)
+    on_chip = lambda t: jax.tree.map(  # noqa: E731
+        lambda a: _arg(a.shape, a.dtype, one_chip), t)
+    params = on_chip(jax.eval_shape(lambda: M.init(cfg, jax.random.key(0))))
+    pool = on_chip(eng.pool)
+    i32 = lambda *shape: _arg(shape, np.int32, one_chip)  # noqa: E731
+    if exe == "serve_decode":
+        lowered = eng._decode_exe(8).lower(
+            params, i32(8, 1), pool, i32(8, eng.pmax), i32(8))
+    else:
+        lowered = eng._prefill_exe(1, 512).lower(
+            params, i32(1, 512), i32(1, 512), pool, i32(1, 512),
+            i32(1, 512), i32(1))
+    return lowered.compile().as_text()
+
+
+@pytest.mark.parametrize("exe", ["serve_decode", "serve_prefill"])
+def test_serving_reads_expert_weights_in_place(one_chip, exe):
+    """The dropless mixture reads each expert out of the ``[L, E, d, f]``
+    stacks at (layer, expert).  A layer loop that sliced the layer's
+    ``[E, d, f]`` slab would make it the operand of the nested expert
+    loop, and XLA copies that slab whole every step (a third of a decode
+    step at these widths on v5e)."""
+    E, d, f = GRANITE.n_experts, GRANITE.d_model, GRANITE.d_ff
+    slab = re.compile(
+        rf"= bf16\[({E},{d},{f}|{E},{f},{d})\]\{{[^}}]*\}} "
+        r"(dynamic-slice|copy|fusion)\(")
+    text = _granite_serving_hlo(one_chip, exe)
+    assert f"bf16[{GRANITE.n_layers},{E},{d},{f}]" in text
+    copies = [ln.strip()[:160] for ln in text.splitlines()
+              if slab.search(ln)]
+    assert not copies, copies
