@@ -4,15 +4,19 @@ the names in ``BENCHMARK.json``, runs its driver and prints the result.
 Everything that belongs to one configuration, traffic mix, cell or metric
 sits in a file of its own:
 
-    bench/configs/<config>.json     sizes as run (spec.py reads it)
+    bench/configs/<config>.json     sizes as run; its "architecture"
+                                    names the module below
+    bench/arch/<architecture>.py    dimensions, weight table, reference
+                                    and FLOP counts of a kind of model
+                                    (see spec.py)
     bench/traffic/<traffic>.json    parameters of one job or mix; its
                                     "driver" names the module under
                                     bench/lib/ that runs it
     bench/limits/<workload>.json    the limit of each compared number
     bench/metrics/<metric>.py       read(ctx) -> value or None
 
-so a later change adds a cell, a mix, a configuration or a metric by
-adding files and entries only.
+so a later change adds a cell, a mix, a configuration, an architecture or
+a metric by adding files and entries only.
 """
 from __future__ import annotations
 

@@ -26,7 +26,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from . import flops, laws, peaks, reference, spec, weights
+from . import flops, laws, peaks, spec, weights
 from . import trace as tracing
 from .harness import ROOT, peak_memory, settle_heap
 
@@ -126,24 +126,24 @@ class Judge:
 
     def __init__(self, dm, wkey, pad: int, mode: str = "f32"):
         self.dm, self.wkey, self.pad, self.mode = dm, wkey, pad, mode
-        self.layer_names = tuple(n for n in reference.LAYER_KEYS
-                                 if n in weights.shapes(dm))
+        arch = spec.arch_of(dm)
+        # embed and head draw every leaf not stacked over layers; the
+        # compiler drops those each leaves unread
+        top = tuple(n for n in arch.shapes(dm) if n not in arch.LAYERED)
         pdt = getattr(jnp, dm.param_dtype)
 
         def block(wkey, lo, x, pos):
             w = weights.draw(dm, wkey, pdt, lo=lo, count=REF_BLOCK,
-                             names=self.layer_names)
-            return reference.hidden(w, None, dm, mode, x=x, pos=pos)
+                             names=arch.LAYERED)
+            return arch.hidden(w, None, dm, mode, x=x, pos=pos)
 
         def embed(wkey, toks):
-            w = weights.draw(dm, wkey, pdt, names=("embed",))
-            return w["embed"][toks].astype(jnp.float32) * math.sqrt(
-                dm.d_model)
+            return arch.embed(weights.draw(dm, wkey, pdt, names=top), toks,
+                              dm)
 
         def logits(wkey, x):
-            names = ("final_norm", "embed" if dm.tied else "lm_head")
-            return reference.head(weights.draw(dm, wkey, pdt, names=names),
-                                  x, dm, mode)[0]
+            return arch.head(weights.draw(dm, wkey, pdt, names=top), x, dm,
+                             mode)[0]
 
         self._block = jax.jit(block)
         self._embed = jax.jit(embed)
@@ -193,7 +193,7 @@ def run(*, workload, config, traffic, limits, devs, seed, seconds, trace,
     mix = traffic
     cfg_spec = spec.load(config)
     dm = dims or spec.dims(cfg_spec)
-    cfg = spec.program_config(dm, config, remat=False)
+    cfg = spec.arch_of(dm).program_config(dm, config, remat=False)
     if trace:
         seconds = min(seconds, mix["trace_seconds"])
     sched = schedule(mix, seed, seconds, dm.vocab)
@@ -205,7 +205,7 @@ def run(*, workload, config, traffic, limits, devs, seed, seconds, trace,
     wkey = weights.key(seed)
     with jax.default_device(devs[0]):
         params = jax.jit(lambda k: weights.to_program(
-            weights.draw(dm, k)))(wkey)
+            dm, weights.draw(dm, k)))(wkey)
         eng = ServeEngine(cfg, params, n_pages=mix["n_pages"],
                           page_size=mix["page_size"], max_seq=mix["max_seq"],
                           max_batch=mix["max_batch"],

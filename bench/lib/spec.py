@@ -1,40 +1,41 @@
-"""Model configurations: the JSON file under ``bench/configs/`` -> the
-program's ``ModelConfig`` and the plain dimensions the reference, the
-weight generator and the FLOP counts read.
+"""Model configurations: the JSON file under ``bench/configs/`` and the
+architecture it names, the module ``bench/arch/<architecture>.py``.
 
-The file holds the published ``config.json`` keys; ``assumed`` holds the
-sizes and dtypes the source leaves open, and ``qk_norm`` says whether the
-architecture normalises queries and keys (its ``model_type`` implies it;
-no published key says it).  Any decoder-only model with these keys maps.
+An architecture module holds everything the benchmark knows of a model's
+shape and mathematics, and nothing else:
+
+* ``Dims`` and ``dims(spec)``: the plain dimensions read from the file.
+  Every ``Dims`` has ``n_layers``, ``vocab`` and ``param_dtype``, which
+  the drivers and the weight generator read;
+* ``program_config(dm, name, *, remat, attention_impl)``: the program's
+  ``ModelConfig`` for them;
+* the weight table: ``shapes(dm)`` (name -> (shape, init scale)),
+  ``PATHS`` (name -> path in the program's parameter tree), ``LAYERED``
+  (the leaves stacked over layers) and ``F32`` (the leaves the program
+  keeps in float32);
+* the plain float32 reference, built on ``reference``'s primitives:
+  ``embed(w, tokens, dm)``, ``hidden(w, tokens, dm, mode, *, lo, hi, x,
+  pos)``, ``head(w, x, dm, mode)`` and ``loss(w, tokens, dm, mode,
+  fault)``;
+* the counts the FLOP formulas need: ``active_params(dm, name, size)``,
+  ``matmul_params_per_token(dm)`` and ``attention_flops(dm, context)``.
+
+A ``Dims`` belongs to the architecture module that defines its class
+(``arch_of``), so code handed one finds the rest by it.
 """
 from __future__ import annotations
 
 import dataclasses
+import importlib.util
 import json
 import os
+import sys
 
 BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
-@dataclasses.dataclass(frozen=True)
-class Dims:
-    """What the reference needs of a decoder-only transformer."""
-    n_layers: int
-    d_model: int
-    n_heads: int
-    n_kv: int
-    head_dim: int
-    d_ff: int
-    vocab: int
-    rope_theta: float
-    eps: float
-    qk_norm: bool
-    tied: bool
-    n_experts: int = 0
-    top_k: int = 0
-    param_dtype: str = "float32"
-    activation_dtype: str = "bfloat16"
-    embed_init_scale: float = 1.0
+def _arch_path(name: str, base: str) -> str:
+    return os.path.join(base, "arch", f"{name}.py")
 
 
 def load(name: str, base: str = BENCH) -> dict:
@@ -43,45 +44,38 @@ def load(name: str, base: str = BENCH) -> dict:
         spec = json.load(f)
     if spec.get("name") != name:
         raise ValueError(f"{path} names itself {spec.get('name')!r}")
+    arch = spec.get("architecture")
+    if not isinstance(arch, str) or not os.path.isfile(_arch_path(arch, base)):
+        raise ValueError(f"{path} names the architecture {arch!r}, which "
+                         f"is no file under {os.path.join(base, 'arch')}")
     return spec
 
 
-def dims(spec: dict) -> Dims:
-    assumed = spec.get("assumed", {})
-    d = spec["hidden_size"]
-    heads = spec["num_attention_heads"]
-    return Dims(
-        n_layers=spec["num_hidden_layers"], d_model=d, n_heads=heads,
-        n_kv=spec["num_key_value_heads"],
-        head_dim=spec.get("head_dim", d // heads),
-        d_ff=spec["intermediate_size"], vocab=spec["vocab_size"],
-        rope_theta=float(spec["rope_theta"]), eps=float(spec["rms_norm_eps"]),
-        qk_norm=bool(spec.get("qk_norm", False)), tied=bool(spec["tie_word_embeddings"]),
-        n_experts=spec.get("num_local_experts", 0),
-        top_k=spec.get("num_experts_per_tok", 0),
-        param_dtype=assumed.get("param_dtype", "float32"),
-        activation_dtype=assumed.get("activation_dtype", "bfloat16"),
-        embed_init_scale=assumed.get("embed_init_scale", 1.0))
+def architecture(name: str, base: str = BENCH):
+    """The module ``bench/arch/<name>.py`` under ``base``, loaded once."""
+    path = _arch_path(name, base)
+    modname = "bench_arch_" + name.replace("-", "_").replace(".", "_")
+    mod = sys.modules.get(modname)
+    if mod is None or mod.__file__ != path:
+        spec = importlib.util.spec_from_file_location(modname, path)
+        mod = importlib.util.module_from_spec(spec)
+        # registered before it runs, so that its classes name a module
+        # that ``arch_of`` finds
+        sys.modules[modname] = mod
+        spec.loader.exec_module(mod)
+    return mod
 
 
-def shrink(dm: Dims, **kw) -> Dims:
+def dims(spec: dict, base: str = BENCH):
+    """A configuration's dimensions, as its architecture reads them."""
+    return architecture(spec["architecture"], base).dims(spec)
+
+
+def arch_of(dm):
+    """The architecture module that defines ``dm``'s class."""
+    return sys.modules[type(dm).__module__]
+
+
+def shrink(dm, **kw):
     """A smaller copy for the CPU tests (never used on the chip)."""
     return dataclasses.replace(dm, **kw)
-
-
-def program_config(dm: Dims, name: str, *, remat: bool = True,
-                   attention_impl: str = "jnp"):
-    """The program's ``ModelConfig`` for these dimensions."""
-    import jax.numpy as jnp
-
-    from repro.models.model import ModelConfig
-
-    return ModelConfig(
-        name=name, family="moe" if dm.n_experts else "dense",
-        n_layers=dm.n_layers, d_model=dm.d_model, n_heads=dm.n_heads,
-        n_kv_heads=dm.n_kv, head_dim=dm.head_dim, d_ff=dm.d_ff,
-        vocab_size=dm.vocab, qk_norm=dm.qk_norm, rope_theta=dm.rope_theta,
-        tie_embeddings=dm.tied, n_experts=dm.n_experts, top_k=dm.top_k,
-        norm_eps=dm.eps, param_dtype=getattr(jnp, dm.param_dtype),
-        activation_dtype=getattr(jnp, dm.activation_dtype), remat=remat,
-        attention_impl=attention_impl)

@@ -12,6 +12,7 @@ the plain float32 reference runs the same first steps from the same seed.
 """
 from __future__ import annotations
 
+import functools
 import gc
 import os
 import shutil
@@ -52,9 +53,10 @@ def _program_observations(step_for, params, state, batches, lr, dm, wkey,
         losses.append(loss)
         if k == 0:
             grad = compare.node_leaf_norms(weights.from_program(
-                state.momentum))
+                dm, state.momentum))
         if k == FIRST_STEPS - 1:
-            obs = compare.change_obs(weights.from_program(params), dm, wkey)
+            obs = compare.change_obs(weights.from_program(dm, params), dm,
+                                     wkey)
     obs.update(losses=[float(x) for x in losses[:FIRST_STEPS]],
                grad={k: np.asarray(v) for k, v in grad.items()})
     return obs, params, state
@@ -64,7 +66,8 @@ def _reference_observations(dm, devs, n, batches, lr, beta, wkey,
                             mode="f32", fault=None):
     mesh = Mesh(np.array(devs), ("node",))
     node = NamedSharding(mesh, P("node"))
-    ref = reference.DmSGD(dm, mesh, beta=beta, lr=lr, mode=mode, fault=fault)
+    ref = reference.DmSGD(functools.partial(spec.arch_of(dm).loss, dm=dm),
+                          mesh, beta=beta, lr=lr, mode=mode, fault=fault)
 
     def start(wkey):
         flat = weights.draw(dm, wkey, dtype=jnp.float32)
@@ -97,7 +100,7 @@ def run(*, workload, config, traffic, limits, devs, seed, seconds, trace,
     cfg_spec = spec.load(config)
     dm = dims or spec.dims(cfg_spec)
     remat = cfg_spec.get("assumed", {}).get("remat", True)
-    cfg = spec.program_config(dm, config, remat=remat)
+    cfg = spec.arch_of(dm).program_config(dm, config, remat=remat)
     n = traffic["nodes"]
     if len(devs) != n:
         raise ValueError(f"{n} nodes need {n} chips, got {len(devs)}")
@@ -115,7 +118,7 @@ def run(*, workload, config, traffic, limits, devs, seed, seconds, trace,
     bkey = jax.random.key(weights.seed32(seed, salt=2))
 
     def stacked(wkey):
-        tree = weights.to_program(weights.draw(dm, wkey))
+        tree = weights.to_program(dm, weights.draw(dm, wkey))
         return jax.tree.map(lambda p: jnp.broadcast_to(p, (n,) + p.shape),
                             tree)
 

@@ -92,7 +92,7 @@ def test_a_new_cell_needs_only_new_files_and_entries(tmp_path):
 
     b = harness.benchmark(str(tmp_path))
     w = harness.cell(b, "toy-cell")
-    assert spec.dims(spec.load(w["config"], base)).n_layers == 2
+    assert spec.dims(spec.load(w["config"], base), base).n_layers == 2
     assert harness.traffic(w["traffic"], base)["lr"] == 0.01
     assert harness.limits("toy-cell", base) == {"loss": 1.0}
     names = [m["name"] for m in harness.per_layer(b, "toy-cell")]
@@ -100,6 +100,166 @@ def test_a_new_cell_needs_only_new_files_and_entries(tmp_path):
     assert harness.reader("toy.steps", base)({"steps": 9}) == 9
     after = _digest(tmp_path / "bench")
     assert {k: v for k, v in after.items() if k in before} == before
+
+
+@pytest.mark.parametrize("arch", [None, "no-such-arch"],
+                         ids=["missing", "unknown"])
+def test_a_config_names_an_architecture_that_exists(arch, tmp_path):
+    cfg = {k: v for k, v in spec.load("qwen3-0.6b").items()
+           if k != "architecture"}
+    if arch:
+        cfg["architecture"] = arch
+    (tmp_path / "configs").mkdir()
+    path = tmp_path / "configs" / "toy.json"
+    path.write_text(json.dumps(dict(cfg, name="toy")))
+    with pytest.raises(ValueError, match=str(path)):
+        spec.load("toy", str(tmp_path))
+
+
+# A decoder variant that bench/arch/decoder.py cannot express: attention
+# sees only the last ``window`` tokens (the program's static
+# ``sliding_window``).  Its reference reaches back ``{reach}`` tokens.
+TOY_ARCH = textwrap.dedent('''
+    import dataclasses
+    import math
+
+    import jax
+    import jax.numpy as jnp
+
+    from bench.lib import spec
+    from bench.lib.reference import HIGHEST, mm, next_token_loss, rms, rope
+
+    decoder = spec.architecture("decoder")
+    PATHS, LAYERED, F32 = decoder.PATHS, decoder.LAYERED, decoder.F32
+    shapes, embed, head = decoder.shapes, decoder.embed, decoder.head
+    active_params = decoder.active_params
+    matmul_params_per_token = decoder.matmul_params_per_token
+    attention_flops = decoder.attention_flops   # as if it saw every key
+
+
+    @dataclasses.dataclass(frozen=True)
+    class Dims(decoder.Dims):
+        window: int = 0
+
+
+    def dims(spec):
+        return Dims(**dataclasses.asdict(decoder.dims(spec)),
+                    window=spec["sliding_window"])
+
+
+    def program_config(dm, name, **kw):
+        return dataclasses.replace(decoder.program_config(dm, name, **kw),
+                                   sliding_window=dm.window)
+
+
+    def hidden(w, tokens, dm, mode="f32", *, lo=0, hi=None, x=None,
+               pos=None):
+        if pos is None:
+            pos = jnp.broadcast_to(jnp.arange(tokens.shape[1]), tokens.shape)
+        if x is None:
+            x = embed(w, tokens, dm)
+        stack = {{k: w[k][lo:hi].astype(jnp.float32) for k in LAYERED
+                 if k in w}}
+        H, Kv, hd = dm.n_heads, dm.n_kv, dm.head_dim
+        i, j = pos[:, None, :, None], pos[:, None, None, :]
+        seen = (i >= j) & (j > i - {reach})
+
+        def layer(x, lw):
+            B, S, _ = x.shape
+            h = rms(x, lw["attn_norm"], dm.eps)
+            q = mm("bsd,dh->bsh", h, lw["wq"], mode).reshape(B, S, H, hd)
+            k = mm("bsd,dh->bsh", h, lw["wk"], mode).reshape(B, S, Kv, hd)
+            v = mm("bsd,dh->bsh", h, lw["wv"], mode).reshape(B, S, Kv, hd)
+            q, k = rms(q, lw["q_norm"], dm.eps), rms(k, lw["k_norm"], dm.eps)
+            q, k = rope(q, pos, dm.rope_theta), rope(k, pos, dm.rope_theta)
+            k, v = jnp.repeat(k, H // Kv, 2), jnp.repeat(v, H // Kv, 2)
+            s = jnp.einsum("bshd,bthd->bhst", q, k, precision=HIGHEST)
+            p = jax.nn.softmax(jnp.where(seen, s / math.sqrt(hd), -jnp.inf),
+                               -1)
+            o = jnp.einsum("bhst,bthd->bshd", p, v, precision=HIGHEST)
+            x = x + mm("bsh,hd->bsd", o.reshape(B, S, H * hd), lw["wo"], mode)
+            h = rms(x, lw["mlp_norm"], dm.eps)
+            g = mm("bsd,df->bsf", h, lw["w_gate"], mode)
+            u = mm("bsd,df->bsf", h, lw["w_up"], mode)
+            return x + mm("bsf,fd->bsd", jax.nn.silu(g) * u, lw["w_down"],
+                          mode), None
+
+        x, _ = jax.lax.scan(layer, x, stack)
+        return x
+
+
+    def loss(w, tokens, dm, mode="f32", fault=None):
+        return next_token_loss(head(w, hidden(w, tokens, dm, mode), dm, mode),
+                               tokens, fault)
+''')
+
+TOY_RUN = textwrap.dedent("""
+    import json, sys
+    sys.path[:0] = [{root!r}, {src!r}]
+    from bench.tests.benchtest import run_cell, tiny
+    print(json.dumps([
+        run_cell("toy-train", root={root!r}),
+        run_cell("toy-serve", root={root!r},
+                 dims=tiny("toy-window", n_layers=4, tied=False))]))
+""")
+
+
+@pytest.mark.parametrize("reach,correct", [("dm.window", True),
+                                           ("2 ** 30", False)],
+                         ids=["reference-agrees", "reference-sees-all"])
+def test_a_new_architecture_needs_only_new_files_and_entries(
+        reach, correct, tmp_path):
+    """An architecture, a configuration naming it, a traffic file, limits
+    and two cells, added as new files and entries: the training and the
+    serving rehearsal run them at tiny widths through the copy's own
+    harness, and no file that was there changes.  Where the toy's
+    reference ignores the window, as the decoder's would, both cells read
+    ``correct`` false: the check used the toy's reference."""
+    shutil.copytree(BENCH, tmp_path / "bench",
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    shutil.copy(os.path.join(ROOT, "BENCHMARK.json"), tmp_path)
+    before = _digest(tmp_path / "bench")
+    bench = json.loads((tmp_path / "BENCHMARK.json").read_text())
+    train, serve = (
+        next(w for w in bench["workloads"]
+             if harness.traffic(w["traffic"])["driver"] == d)
+        for d in ("train_driver", "serve_driver"))
+
+    (tmp_path / "bench/arch/toy.py").write_text(TOY_ARCH.format(reach=reach))
+    (tmp_path / "bench/configs/toy-window.json").write_text(json.dumps(dict(
+        spec.load(train["config"]), name="toy-window", architecture="toy",
+        sliding_window=4)))
+    (tmp_path / "bench/traffic/toy-chat.json").write_text(json.dumps(
+        harness.traffic(serve["traffic"])))
+    (tmp_path / "bench/limits/toy-train.json").write_text(json.dumps(
+        harness.limits(train["name"])))
+    (tmp_path / "bench/limits/toy-serve.json").write_text(
+        json.dumps({"served_gap": 0.2}))
+    bench["configs"].append({"name": "toy-window", "source": "x",
+                             "file": "bench/configs/toy-window.json",
+                             "reduced": [], "why": "toy"})
+    for name, traffic, like in (("toy-train", train["traffic"], train),
+                                ("toy-serve", "toy-chat", serve)):
+        bench["workloads"].append({"name": name, "config": "toy-window",
+                                   "traffic": traffic, "chips": 1,
+                                   "why": "toy"})
+        for m in bench["end_to_end"] + bench["per_layer"]:
+            if like["name"] in m.get("workloads", []):
+                m["workloads"].append(name)
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(bench))
+
+    script = tmp_path / "toy.py"
+    script.write_text(TOY_RUN.format(root=str(tmp_path),
+                                     src=os.path.join(ROOT, "src")))
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    env.pop("PYTHONPATH", None)
+    r = subprocess.run([sys.executable, str(script)], cwd=str(tmp_path),
+                       env=env, capture_output=True, text=True, timeout=900)
+    assert r.returncode == 0, r.stderr[-3000:]
+    for res in json.loads(r.stdout.splitlines()[-1]):
+        assert res["correct"] is correct, res["checks"]
+        assert res["attempted"] > 0 and res["failed"] == 0
+    assert _digest(tmp_path / "bench").items() >= before.items()
 
 
 def _run(cwd, env_extra=None):
